@@ -8,7 +8,11 @@ Prints ``name,us_per_call,derived`` CSV rows. Roofline/dry-run artifacts
 macro windows) and checks the macro-tick dispatch accounting without
 touching the recorded BENCH_throughput.json baseline. ``--lane`` adds the
 lane-sharded curve (bench_lane_scale) — a subprocess, because the forced
-host-device count must be set before jax imports.
+host-device count must be set before jax imports. That child is pinned to
+the CPU, since a chip belongs to the process that touched JAX first; the
+full run records the curve only when it runs on the CPU itself, as
+forced-host-device timings say nothing about a chip. A phase that fails
+fails the run.
 """
 from __future__ import annotations
 
@@ -29,7 +33,7 @@ def lane_bench(smoke: bool) -> dict:
     out_path = os.path.join(ROOT, "benchmarks", "artifacts", name)
     cmd = [sys.executable, os.path.join(ROOT, "benchmarks", "bench_lane_scale.py"),
            "--out", out_path] + (["--smoke"] if smoke else [])
-    subprocess.run(cmd, check=True, cwd=ROOT)
+    subprocess.run(cmd, check=True, cwd=ROOT, env={**os.environ, "JAX_PLATFORMS": "cpu"})
     with open(out_path) as f:
         return json.load(f)
 
@@ -254,7 +258,12 @@ def transport_smoke() -> dict:
 
 
 def main() -> None:
-    from benchmarks import bench_kernels, bench_synapse_quality, bench_table1, bench_table2, bench_throughput
+    import jax
+
+    from benchmarks import (
+        bench_hibernate, bench_kernels, bench_serving, bench_synapse_quality,
+        bench_table1, bench_table2, bench_throughput,
+    )
 
     print("name,us_per_call,derived")
     results = {}
@@ -265,42 +274,25 @@ def main() -> None:
         ("throughput", bench_throughput),
         ("kernels", bench_kernels),
     ]:
-        try:
-            results[name] = mod.run()
-        except Exception as e:  # keep the harness going; record the failure
-            print(f"{name},0,FAILED:{type(e).__name__}:{e}")
-            results[name] = {"error": str(e)}
+        results[name] = mod.run()
     os.makedirs("benchmarks/artifacts", exist_ok=True)
     with open("benchmarks/artifacts/bench_results.json", "w") as f:
         json.dump(results, f, indent=1, default=str)
 
     # top-level perf-trajectory artifact: tick latency per side-count plus
-    # the engine's dispatch/sync counters, tracked across PRs. Never clobber
-    # the recorded baseline with a failed run.
-    throughput = results.get("throughput", {})
-    if throughput and "error" not in throughput:
-        try:
-            lane = lane_bench(smoke=False)
-            throughput["lane_mesh_shape"] = lane["lane_mesh_shape"]
-            throughput["lane_scale"] = lane["per_n_side"]
-        except Exception as e:
-            print(f"lane_scale,0,FAILED:{type(e).__name__}:{e}")
-        try:
-            from benchmarks import bench_hibernate
-
-            throughput["hibernate"] = bench_hibernate.run()
-        except Exception as e:
-            print(f"hibernate,0,FAILED:{type(e).__name__}:{e}")
-        try:
-            from benchmarks import bench_serving
-
-            throughput["serving"] = bench_serving.run()
-            # in-process vs loopback wire overhead (ISSUE 10)
-            throughput["serving"]["transport"] = bench_serving.transport_ab()
-        except Exception as e:
-            print(f"serving,0,FAILED:{type(e).__name__}:{e}")
-        with open(os.path.join(ROOT, "BENCH_throughput.json"), "w") as f:
-            json.dump(throughput, f, indent=1, default=str)
+    # the engine's dispatch/sync counters, tracked across PRs. Written only
+    # once every phase has passed.
+    throughput = results["throughput"]
+    if jax.default_backend() == "cpu":
+        lane = lane_bench(smoke=False)
+        throughput["lane_mesh_shape"] = lane["lane_mesh_shape"]
+        throughput["lane_scale"] = lane["per_n_side"]
+    throughput["hibernate"] = bench_hibernate.run()
+    throughput["serving"] = bench_serving.run()
+    # in-process vs loopback wire overhead
+    throughput["serving"]["transport"] = bench_serving.transport_ab()
+    with open(os.path.join(ROOT, "BENCH_throughput.json"), "w") as f:
+        json.dump(throughput, f, indent=1, default=str)
 
 
 if __name__ == "__main__":
@@ -320,6 +312,9 @@ if __name__ == "__main__":
                     help="with --smoke: run ONLY the HTTP/SSE transport smoke "
                          "(loopback A/B, writes bench_transport_smoke.json)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         if args.chaos:
             chaos_smoke()

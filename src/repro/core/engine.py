@@ -386,14 +386,26 @@ def _set_lane_samp(samp_a: LaneSampling, lane, temp, tk, tp) -> LaneSampling:
     )
 
 
-def _spawn_lane(cfg: ModelConfig, side_spec, main_caches, side_caches, parent_lane, side_lane):
+def _spawn_lane(cfg: ModelConfig, side_spec, main_caches, side_caches, parent_lane, side_lane,
+                *, mesh=None):
     """Compress ONE parent lane and scatter it into ONE side lane — no
     all-lane vmap, no full-tree copies (the legacy path compressed every
-    main lane to use one)."""
-    parent = jax.tree.map(
-        lambda a: jax.lax.dynamic_slice_in_dim(a, parent_lane, 1, axis=1), main_caches
-    )
-    comp = spawn_caches(cfg, parent, side_spec)
+    main lane to use one).
+
+    On a lane ``mesh`` the compression runs under ``shard_map`` with every
+    operand replicated: GSPMD cannot partition a Pallas kernel, so each
+    device compresses the replicated parent lane itself, and only the
+    scatter into the lane-sharded side caches is left to GSPMD."""
+    def compress(main_caches, parent_lane):
+        parent = jax.tree.map(
+            lambda a: jax.lax.dynamic_slice_in_dim(a, parent_lane, 1, axis=1), main_caches
+        )
+        return spawn_caches(cfg, parent, side_spec)
+
+    if mesh is not None:
+        rep = jax.sharding.PartitionSpec()
+        compress = sharded_lib.shard_map_nocheck(compress, mesh, in_specs=(rep, rep), out_specs=rep)
+    comp = compress(main_caches, parent_lane)
     return jax.tree.map(
         lambda d, s: jax.lax.dynamic_update_slice_in_dim(d, s.astype(d.dtype), side_lane, axis=1),
         side_caches,
@@ -734,7 +746,7 @@ class CortexEngine:
             (rep, rep, ssh.main_caches) if ssh else None,
         )
         self._jit_spawn = _jit(
-            partial(_spawn_lane, jcfg, self.side_spec), (1,),
+            partial(_spawn_lane, jcfg, self.side_spec, mesh=self.mesh if ssh else None), (1,),
             ssh.side_caches if ssh else None,
         )
         self._jit_merge = _jit(

@@ -31,24 +31,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jax >= 0.6: public jax.shard_map, replication check spelled check_vma
-    from jax import shard_map as _shard_map
-
-    _SM_NOCHECK = {"check_vma": False}
-except ImportError:  # jax <= 0.5: experimental module, spelled check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SM_NOCHECK = {"check_rep": False}
+from jax import shard_map as _shard_map
 
 NEG_INF = -1e30
 
 
 def shard_map_nocheck(fn, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` with the replication check disabled
-    (the engine's macro tick mixes replicated main-lane state with
-    lane-sharded side state — the static checker cannot prove that)."""
+    """``shard_map`` with the replication check disabled (the engine's
+    macro tick mixes replicated main-lane state with lane-sharded side
+    state — the static checker cannot prove that)."""
     return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **_SM_NOCHECK)
+                      check_vma=False)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -9,8 +9,8 @@ is read from HBM exactly once per step.
 
 Tiling: grid (B, Hkv); per program the full [T, D] K and V tiles for one kv
 head live in VMEM (T<=8192, D<=256 -> <=8 MiB bf16), queries are the G = H/Hkv
-group rows. Scores run in fp32 on the MXU; D and T should be multiples of
-128 for lane alignment (callers pad — see ops.py).
+group rows. Scores accumulate in fp32 on the MXU; D and T must be multiples
+of 128 for lane alignment (callers pad — see ops.py).
 """
 from __future__ import annotations
 
@@ -19,6 +19,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.landmark_score import mxu_dot
 
 NEG_INF = -1e30
 
@@ -51,30 +53,23 @@ def _kernel_batched(q_ref, k_ref, v_ref, valid_ref, o_ref, mass_ref, *, scale: f
 
 
 def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, mass_ref, *, scale: float):
+    # One program per (batch row, kv head):
     # q_ref:    [G, D]      queries of this kv head's group
     # k_ref:    [T, D]      keys (one kv head)
     # v_ref:    [T, D]      values
-    # valid_ref:[T]         int8 mask
+    # valid_ref:[1, T]      int32 mask, a lane-major row
     # o_ref:    [G, D]      attention output
-    # mass_ref: [T]         per-key probability mass summed over the G heads
-    q = q_ref[...].astype(jnp.float32)
-    k = k_ref[...].astype(jnp.float32)
-    v = v_ref[...].astype(jnp.float32)
+    # mass_ref: [1, T]      per-key probability mass summed over the G heads
     valid = valid_ref[...] != 0
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [G, T]
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = mxu_dot(q_ref[...], k_ref[...], (((1,), (1,)), ((), ()))) * scale  # [G, T]
+    s = jnp.where(valid, s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.exp(s - m)
     denom = jnp.sum(e, axis=-1, keepdims=True)
     p = e / denom  # [G, T]
-    o = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [G, D]
+    o = mxu_dot(p, v_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())))  # [G, D]
     o_ref[...] = o.astype(o_ref.dtype)
-    mass_ref[...] = jnp.sum(p, axis=0).astype(mass_ref.dtype)
+    mass_ref[...] = jnp.sum(p, axis=0, keepdims=True).astype(mass_ref.dtype)
 
 
 def synapse_attention(
@@ -94,16 +89,13 @@ def synapse_attention(
     scale = (1.0 / (D ** 0.5)) if scale is None else scale
     batched = interpret if batched is None else batched
     qg = q.reshape(B, Hkv, G, D)
-    kt = keys.swapaxes(1, 2)  # [B, Hkv, T, D]
-    vt = values.swapaxes(1, 2)
-    valid8 = valid.astype(jnp.int8)
 
     if batched:
         BB = B * Hkv
         qb = qg.swapaxes(1, 0).reshape(BB, G, D)      # [Hkv*B, G, D]
-        kb = kt.swapaxes(1, 0).reshape(BB, T, D)
-        vb = vt.swapaxes(1, 0).reshape(BB, T, D)
-        validb = jnp.tile(valid8, (Hkv, 1))           # [Hkv*B, T]
+        kb = keys.transpose(2, 0, 1, 3).reshape(BB, T, D)
+        vb = values.transpose(2, 0, 1, 3).reshape(BB, T, D)
+        validb = jnp.tile(valid.astype(jnp.int8), (Hkv, 1))  # [Hkv*B, T]
         out, mass = pl.pallas_call(
             functools.partial(_kernel_batched, scale=scale),
             grid=(1,),
@@ -127,24 +119,29 @@ def synapse_attention(
         mass = mass.reshape(Hkv, B, T).sum(axis=0)
         return out, mass
 
-    grid = (B, Hkv)
+    # grid (B, Hkv). Keys stay in their [B, T, Hkv*D] cache layout (a free
+    # reshape): each program's [T, D] tile is the h-th D-wide column block,
+    # so no head-major copy of the key set is made. The mask and the mass
+    # travel as [B, 1, T] / [B, Hkv, 1, T] rows whose last two block dims
+    # equal the array dims, as the TPU's (8, 128) tiling requires.
+    valid_rows = valid.astype(jnp.int32)[:, None, :]
     out, mass = pl.pallas_call(
         functools.partial(_kernel, scale=scale),
-        grid=grid,
+        grid=(B, Hkv),
         in_specs=[
             pl.BlockSpec((None, None, G, D), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, T, D), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, T, D), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((None, T), lambda b, h: (b, 0)),
+            pl.BlockSpec((None, T, D), lambda b, h: (b, 0, h)),
+            pl.BlockSpec((None, T, D), lambda b, h: (b, 0, h)),
+            pl.BlockSpec((None, 1, T), lambda b, h: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, None, G, D), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, T), lambda b, h: (b, h, 0)),
+            pl.BlockSpec((None, None, 1, T), lambda b, h: (b, h, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-            jax.ShapeDtypeStruct((B, Hkv, T), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, 1, T), jnp.float32),
         ],
         interpret=interpret,
-    )(qg, kt, vt, valid8)
-    return out.reshape(B, H, D), mass.sum(axis=1)
+    )(qg, keys.reshape(B, T, Hkv * D), values.reshape(B, T, Hkv * D), valid_rows)
+    return out.reshape(B, H, D), mass.sum(axis=(1, 2))

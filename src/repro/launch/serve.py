@@ -38,6 +38,7 @@ from repro.configs import get_config, list_archs
 from repro.core.engine import CortexEngine
 from repro.core.prism import Prism
 from repro.data.tokenizer import ByteTokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.memory import SynapseStore
 from repro.models import model as model_lib
 from repro.serving.frontend import ServingFrontend
@@ -152,6 +153,7 @@ def main():
                          "the hibernated agents found there before serving")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     params = model_lib.init_params(jax.random.key(0), cfg)
     tok = ByteTokenizer(cfg.vocab_size)

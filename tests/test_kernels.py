@@ -82,3 +82,44 @@ def test_kernel_used_in_synapse_decode_path_is_equivalent():
     out_j, mass_j = decode_attend(q, keys, vals, valid)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_j), rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(mass_k), np.asarray(mass_j), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_synapse_attention_grid_variant_matches_ref(dtype):
+    """The per-(batch, kv head) grid the chip compiles — rows for the mask
+    and the mass, keys read in their cache layout — run under the
+    interpreter at qwen2.5-0.5b's padded head widths."""
+    from repro.kernels import synapse_attention as sa
+
+    B, H, Hkv, D, T = 3, 14, 2, 128, 256
+    ks = jax.random.split(jax.random.key(4), 4)
+    q = jax.random.normal(ks[0], (B, H, D)).astype(dtype)
+    keys = jax.random.normal(ks[1], (B, T, Hkv, D)).astype(dtype)
+    vals = jax.random.normal(ks[2], (B, T, Hkv, D)).astype(dtype)
+    valid = jax.random.bernoulli(ks[3], 0.6, (B, T)).at[:, 0].set(True)
+    out, mass = sa.synapse_attention(q, keys, vals, valid, interpret=True, batched=False)
+    out_r, mass_r = ref.synapse_attention_ref(q, keys, vals, valid)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(out_r, np.float32), **_tol(dtype)
+    )
+    np.testing.assert_allclose(np.asarray(mass), np.asarray(mass_r), **_tol(dtype))
+    assert float(jnp.where(valid, 0.0, mass).max()) == 0.0
+
+
+def test_landmark_score_multi_block_matches_ref():
+    """Several key blocks per row: each block writes its own lane slice of
+    the logits and distance rows."""
+    from repro.kernels import landmark_score as ls
+
+    B, H, Hkv, D, T = 2, 14, 2, 128, 512
+    ks = jax.random.split(jax.random.key(5), 3)
+    q = jax.random.normal(ks[0], (B, H, D))
+    keys = jax.random.normal(ks[1], (B, T, Hkv, D))
+    lm = jax.random.normal(ks[2], (B, 7, D))
+    logits, dist = ls.landmark_score(q, keys, lm, block_t=128, interpret=True)
+    logits_r, dist_r = ref.landmark_score_ref(q, keys, lm)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(logits_r), **_tol(jnp.float32))
+    np.testing.assert_allclose(np.asarray(dist), np.asarray(dist_r), rtol=1e-4, atol=1e-4)
+    dens_only, none = ls.landmark_score(q, keys, None, block_t=128, interpret=True)
+    assert none is None
+    np.testing.assert_array_equal(np.asarray(dens_only), np.asarray(logits))
