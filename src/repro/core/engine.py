@@ -101,6 +101,11 @@ from repro.serving.sampler import (
     sample_lanes, static_flags,
 )
 
+# Host spans of the control plane. With a profiler running they land in its
+# host plane, on the clock the device events are placed on; with none, a
+# span costs about a microsecond. Keyword ids are ones the code already holds.
+_span = jax.profiler.TraceAnnotation
+
 
 def _lane_slice(tree, lane: int):
     """Select batch lane (axis 1 — axis 0 is the stacked layer dim)."""
@@ -354,7 +359,7 @@ def fused_tick(
 # the small per-lane field arrays — never the cache trees, whose buffers may
 # already be donated to the prefill/spawn/merge dispatch of the same event.
 # ---------------------------------------------------------------------------
-def _admit_main_fields(tok_a, pos_a, act_a, hid_a, samp_a, lane, tok, pos, hidden, temp, tk, tp):
+def engine_admit_main(tok_a, pos_a, act_a, hid_a, samp_a, lane, tok, pos, hidden, temp, tk, tp):
     return (
         tok_a.at[lane].set(tok),
         pos_a.at[lane].set(pos),
@@ -364,7 +369,7 @@ def _admit_main_fields(tok_a, pos_a, act_a, hid_a, samp_a, lane, tok, pos, hidde
     )
 
 
-def _admit_side_fields(prompt_a, plen_a, step_a, tok_a, pos_a, act_a, samp_a, lane, prompt, plen, step, last_tok, pos, temp, tk, tp):
+def engine_admit_side(prompt_a, plen_a, step_a, tok_a, pos_a, act_a, samp_a, lane, prompt, plen, step, last_tok, pos, temp, tk, tp):
     # ``step`` is 0 on a fresh spawn; a wake passes the hibernated snapshot's
     # step so the teacher-forcing cursor resumes exactly where it stopped
     return (
@@ -378,6 +383,14 @@ def _admit_side_fields(prompt_a, plen_a, step_a, tok_a, pos_a, act_a, samp_a, la
     )
 
 
+def engine_retire_main(act_a, lane):
+    return act_a.at[lane].set(False)
+
+
+def engine_retire_side(act_a, lane):
+    return act_a.at[lane].set(False)
+
+
 def _set_lane_samp(samp_a: LaneSampling, lane, temp, tk, tp) -> LaneSampling:
     return LaneSampling(
         temperature=samp_a.temperature.at[lane].set(temp),
@@ -386,11 +399,11 @@ def _set_lane_samp(samp_a: LaneSampling, lane, temp, tk, tp) -> LaneSampling:
     )
 
 
-def _spawn_lane(cfg: ModelConfig, side_spec, main_caches, side_caches, parent_lane, side_lane,
-                *, mesh=None):
-    """Compress ONE parent lane and scatter it into ONE side lane — no
-    all-lane vmap, no full-tree copies (the legacy path compressed every
-    main lane to use one).
+def spawn_program(cfg: ModelConfig, side_spec, mesh=None):
+    """The spawn program ``engine_spawn(main_caches, side_caches,
+    parent_lane, side_lane)``: compress ONE parent lane and scatter it into
+    ONE side lane — no all-lane vmap, no full-tree copies (the legacy path
+    compressed every main lane to use one).
 
     On a lane ``mesh`` the compression runs under ``shard_map`` with every
     operand replicated: GSPMD cannot partition a Pallas kernel, so each
@@ -405,12 +418,16 @@ def _spawn_lane(cfg: ModelConfig, side_spec, main_caches, side_caches, parent_la
     if mesh is not None:
         rep = jax.sharding.PartitionSpec()
         compress = sharded_lib.shard_map_nocheck(compress, mesh, in_specs=(rep, rep), out_specs=rep)
-    comp = compress(main_caches, parent_lane)
-    return jax.tree.map(
-        lambda d, s: jax.lax.dynamic_update_slice_in_dim(d, s.astype(d.dtype), side_lane, axis=1),
-        side_caches,
-        comp,
-    )
+
+    def engine_spawn(main_caches, side_caches, parent_lane, side_lane):
+        comp = compress(main_caches, parent_lane)
+        return jax.tree.map(
+            lambda d, s: jax.lax.dynamic_update_slice_in_dim(d, s.astype(d.dtype), side_lane, axis=1),
+            side_caches,
+            comp,
+        )
+
+    return engine_spawn
 
 
 # ---------------------------------------------------------------------------
@@ -419,7 +436,7 @@ def _spawn_lane(cfg: ModelConfig, side_spec, main_caches, side_caches, parent_la
 # depends only on a lane's own cache/token/position, so restoring these exact
 # bytes into ANY free lane reproduces the agent's token stream bitwise.
 # ---------------------------------------------------------------------------
-def _gather_main_lane(state: TickState, lane):
+def engine_gather_main(state: TickState, lane):
     return {
         "caches": lane_gather(state.main_caches, lane, axis=1),
         "tok": state.main_tok[lane],
@@ -428,7 +445,7 @@ def _gather_main_lane(state: TickState, lane):
     }
 
 
-def _gather_side_lane(state: TickState, lane):
+def engine_gather_side(state: TickState, lane):
     return {
         "caches": lane_gather(state.side_caches, lane, axis=1),
         "tok": state.side_tok[lane],
@@ -438,6 +455,18 @@ def _gather_side_lane(state: TickState, lane):
         "prompt": state.side_prompt[lane],
         "hidden": state.side_hidden[lane],
     }
+
+
+def engine_wake_main_caches(c, part, lane):
+    return lane_scatter(c, part, lane, axis=1)
+
+
+def engine_wake_side_caches(c, part, lane):
+    return lane_scatter(c, part, lane, axis=1)
+
+
+def engine_set_side_hidden(hid_a, lane, h):
+    return hid_a.at[lane].set(h.astype(hid_a.dtype))
 
 
 # byte values the conservative drain gate inspects on the raw token rings
@@ -673,6 +702,8 @@ class CortexEngine:
             # overlapped the next window's device execution, and a histogram
             # of dispatched window lengths (window_hist[w] = count)
             "overlapped_drains": 0, "window_hist": {},
+            # sides started, and [TASK] triggers refused for want of a lane
+            "spawns": 0, "spawns_dropped": 0,
             # tiered-memory telemetry
             "hibernates": 0, "wakes": 0,
             # resilience telemetry (ISSUE 8): wake_failures = transient
@@ -738,59 +769,54 @@ class CortexEngine:
                 return jax.jit(fn, donate_argnums=donate, out_shardings=out)
             return jax.jit(fn, donate_argnums=donate)
 
+        # every auxiliary program is a named function, so each compiles to
+        # a module of its own name (``jit_engine_spawn``, ...) in a trace
+        def engine_prefill(p, toks, c, lane):
+            return model_lib.prefill_lane(p, jcfg, {"tokens": toks}, c, lane, spec=self.main_spec)
+
+        def engine_merge(p, mc, mh, toks, vpos, mask):
+            return injection.merge_thought(p, jcfg, mc, mh, toks, vpos, mask, self.theta)
+
         self._jit_prefill_lane = _jit(
-            lambda p, toks, c, lane: model_lib.prefill_lane(
-                p, jcfg, {"tokens": toks}, c, lane, spec=self.main_spec
-            ),
-            (2,),
-            (rep, rep, ssh.main_caches) if ssh else None,
+            engine_prefill, (2,), (rep, rep, ssh.main_caches) if ssh else None,
         )
         self._jit_spawn = _jit(
-            partial(_spawn_lane, jcfg, self.side_spec, mesh=self.mesh if ssh else None), (1,),
+            spawn_program(jcfg, self.side_spec, mesh=self.mesh if ssh else None), (1,),
             ssh.side_caches if ssh else None,
         )
         self._jit_merge = _jit(
-            lambda p, mc, mh, toks, vpos, mask: injection.merge_thought(
-                p, jcfg, mc, mh, toks, vpos, mask, self.theta
-            ),
-            (1,),
-            (ssh.main_caches, rep, rep) if ssh else None,
+            engine_merge, (1,), (ssh.main_caches, rep, rep) if ssh else None,
         )
         self._jit_admit_main = _jit(
-            _admit_main_fields, (0, 1, 2, 3, 4),
+            engine_admit_main, (0, 1, 2, 3, 4),
             (ssh.main_tok, ssh.main_pos, ssh.main_active, ssh.main_hidden,
              ssh.main_samp) if ssh else None,
         )
         self._jit_admit_side = _jit(
-            _admit_side_fields, (0, 1, 2, 3, 4, 5, 6),
+            engine_admit_side, (0, 1, 2, 3, 4, 5, 6),
             (ssh.side_prompt, ssh.side_plen, ssh.side_step, ssh.side_tok,
              ssh.side_pos, ssh.side_active, ssh.side_samp) if ssh else None,
         )
         self._jit_retire_side = _jit(
-            lambda act_a, lane: act_a.at[lane].set(False), (0,),
-            ssh.side_active if ssh else None,
+            engine_retire_side, (0,), ssh.side_active if ssh else None,
         )
         self._jit_retire_main = _jit(
-            lambda act_a, lane: act_a.at[lane].set(False), (0,),
-            ssh.main_active if ssh else None,
+            engine_retire_main, (0,), ssh.main_active if ssh else None,
         )
         # hibernate/wake lane transfer jits. Gathers replicate their outputs
         # (on a mesh GSPMD inserts the collective pulling a sharded side
         # lane's leaves together); scatters donate the full cache tree and
         # pin its lane sharding so the next macro dispatch aliases cleanly.
-        self._jit_gather_main = _jit(_gather_main_lane, (), rep if ssh else None)
-        self._jit_gather_side = _jit(_gather_side_lane, (), rep if ssh else None)
+        self._jit_gather_main = _jit(engine_gather_main, (), rep if ssh else None)
+        self._jit_gather_side = _jit(engine_gather_side, (), rep if ssh else None)
         self._jit_wake_main_caches = _jit(
-            lambda c, part, lane: lane_scatter(c, part, lane, axis=1), (0,),
-            ssh.main_caches if ssh else None,
+            engine_wake_main_caches, (0,), ssh.main_caches if ssh else None,
         )
         self._jit_wake_side_caches = _jit(
-            lambda c, part, lane: lane_scatter(c, part, lane, axis=1), (0,),
-            ssh.side_caches if ssh else None,
+            engine_wake_side_caches, (0,), ssh.side_caches if ssh else None,
         )
         self._jit_set_side_hidden = _jit(
-            lambda hid_a, lane, h: hid_a.at[lane].set(h.astype(hid_a.dtype)), (0,),
-            ssh.side_hidden if ssh else None,
+            engine_set_side_hidden, (0,), ssh.side_hidden if ssh else None,
         )
 
     def _macro_fn(self, n_ticks: int, step_sides: bool, use_filters: bool, any_greedy: bool):
@@ -895,38 +921,39 @@ class CortexEngine:
         self.drain()  # align host mirrors to a window boundary
         self.window.on_event()  # admission: back to the base window
         aid = self._claim_main_identity(lane, agent_id)
-        ids = self.tok.encode(prompt, bos=True)
-        toks = jnp.asarray([ids], jnp.int32)
-        logits, hidden, new_caches = self._jit_prefill_lane(
-            self._params, toks, self.state.main_caches, lane
-        )
-        self._main_sp[lane] = sampling if sampling is not None else self.sampling
-        temp, tk, tp = lane_values(self._main_sp[lane])
-        tok_a, pos_a, act_a, hid_a, samp_a = self._jit_admit_main(
-            self.state.main_tok, self.state.main_pos, self.state.main_active,
-            self.state.main_hidden, self.state.main_samp,
-            lane, ids[-1], len(ids), hidden[0], temp, tk, tp,
-        )
-        self.state = dataclasses.replace(
-            self.state, main_caches=new_caches, main_tok=tok_a, main_pos=pos_a,
-            main_active=act_a, main_hidden=hid_a, main_samp=samp_a,
-        )
-        self.stats["aux_dispatches"] += 2
-        m = AgentView(aid, lane, "main")
-        self.mains[lane] = m
-        m.text, m.tokens = prompt, list(ids)
-        m.position, m.active, m.steps = len(ids), True, 0
-        m.prompt_len = len(ids)
-        self._decoders[aid] = self.tok.stream_decoder()  # fresh byte stream
-        self.prism.acquire(m.agent_id)
-        rec = self.registry.bind(aid, lane)
-        rec.bound_tick = self.stats["ticks"]
-        self.router.reset(m.agent_id)  # lane may be restarting
-        # triggers already present in the prompt spawn immediately
-        for tr in self.router.feed(m.agent_id, prompt):
-            if tr.kind == "task":
-                self._spawn_side(m, tr.payload)
-        return m
+        with _span("engine.submit", agent=aid):
+            ids = self.tok.encode(prompt, bos=True)
+            toks = jnp.asarray([ids], jnp.int32)
+            logits, hidden, new_caches = self._jit_prefill_lane(
+                self._params, toks, self.state.main_caches, lane
+            )
+            self._main_sp[lane] = sampling if sampling is not None else self.sampling
+            temp, tk, tp = lane_values(self._main_sp[lane])
+            tok_a, pos_a, act_a, hid_a, samp_a = self._jit_admit_main(
+                self.state.main_tok, self.state.main_pos, self.state.main_active,
+                self.state.main_hidden, self.state.main_samp,
+                lane, ids[-1], len(ids), hidden[0], temp, tk, tp,
+            )
+            self.state = dataclasses.replace(
+                self.state, main_caches=new_caches, main_tok=tok_a, main_pos=pos_a,
+                main_active=act_a, main_hidden=hid_a, main_samp=samp_a,
+            )
+            self.stats["aux_dispatches"] += 2
+            m = AgentView(aid, lane, "main")
+            self.mains[lane] = m
+            m.text, m.tokens = prompt, list(ids)
+            m.position, m.active, m.steps = len(ids), True, 0
+            m.prompt_len = len(ids)
+            self._decoders[aid] = self.tok.stream_decoder()  # fresh byte stream
+            self.prism.acquire(m.agent_id)
+            rec = self.registry.bind(aid, lane)
+            rec.bound_tick = self.stats["ticks"]
+            self.router.reset(m.agent_id)  # lane may be restarting
+            # triggers already present in the prompt spawn immediately
+            for tr in self.router.feed(m.agent_id, prompt):
+                if tr.kind == "task":
+                    self._spawn_side(m, tr.payload)
+            return m
 
     def _claim_main_identity(self, lane: int, agent_id: str | None) -> str:
         """Resolve the agent_id a main-lane submit binds, evicting the lane's
@@ -1018,9 +1045,10 @@ class CortexEngine:
         """Advance ``n <= max_window - pending`` virtual ticks in one
         dispatch. No drain, no host sync — callers close the window."""
         assert self._pending + n <= self.max_window
-        step_sides = any(s.active for s in self.sides)
-        fn = self._macro_fn(n, step_sides, *self._sampler_flags(step_sides))
-        self.state = fn(self._params, self.state)
+        with _span("engine.dispatch", n=n):
+            step_sides = any(s.active for s in self.sides)
+            fn = self._macro_fn(n, step_sides, *self._sampler_flags(step_sides))
+            self.state = fn(self._params, self.state)
         self.stats["ticks"] += n
         self.stats["tick_dispatches"] += 1
         if n > 1:
@@ -1218,7 +1246,8 @@ class CortexEngine:
         token rings (host numpy copies), then reset the ring cursor so the
         next dispatch — which donates the ring buffers — starts a fresh
         window immediately."""
-        rings = jax.device_get((self.state.main_ring, self.state.side_ring))
+        with _span("engine.fetch"):
+            rings = jax.device_get((self.state.main_ring, self.state.side_ring))
         self.stats["host_syncs"] += 1
         self._pending = 0
         zero = jnp.zeros((), jnp.int32)
@@ -1236,95 +1265,96 @@ class CortexEngine:
         tasks. With ``overlapped=True`` the next window is already on the
         device, so any control op here would be a gate violation — asserted,
         and by the gate's conservativeness unreachable."""
-        main_ring, side_ring = rings
-        self.stats["drains"] += 1
-        quiet = True
+        with _span("engine.postprocess", overlapped=overlapped):
+            main_ring, side_ring = rings
+            self.stats["drains"] += 1
+            quiet = True
 
-        # 1. rivers: append the window's tokens. Decode is INCREMENTAL
-        # (ISSUE 9 bugfix): a multi-byte codepoint split across the drain
-        # boundary stays buffered in the agent's decoder instead of
-        # becoming U+FFFD — m.text is always a bitwise prefix of the
-        # one-shot decode, and agent_text() exposes the exact final form.
-        main_chunks: dict[int, str] = {}
-        for m in self.mains:
-            if not m.active:
-                continue
-            if ("main", m.lane) in self._fresh_wakes:
-                continue  # woke after this window ran: not on device for it
-            toks = [int(t) for t in main_ring[m.lane, :n] if t >= 0]
-            chunk = self._decoder(m.agent_id).feed(toks)
-            m.tokens.extend(toks)
-            m.text += chunk
-            m.position += len(toks)
-            m.steps += len(toks)
-            main_chunks[m.lane] = chunk
-            if self.stream_tap is not None and toks:
-                self.stream_tap(m, chunk, toks)
+            # 1. rivers: append the window's tokens. Decode is INCREMENTAL
+            # (ISSUE 9 bugfix): a multi-byte codepoint split across the drain
+            # boundary stays buffered in the agent's decoder instead of
+            # becoming U+FFFD — m.text is always a bitwise prefix of the
+            # one-shot decode, and agent_text() exposes the exact final form.
+            main_chunks: dict[int, str] = {}
+            for m in self.mains:
+                if not m.active:
+                    continue
+                if ("main", m.lane) in self._fresh_wakes:
+                    continue  # woke after this window ran: not on device for it
+                toks = [int(t) for t in main_ring[m.lane, :n] if t >= 0]
+                chunk = self._decoder(m.agent_id).feed(toks)
+                m.tokens.extend(toks)
+                m.text += chunk
+                m.position += len(toks)
+                m.steps += len(toks)
+                main_chunks[m.lane] = chunk
+                if self.stream_tap is not None and toks:
+                    self.stream_tap(m, chunk, toks)
 
-        # 2. streams: append, detect completion (trigger or step budget)
-        finished = []
-        for s in self.sides:
-            if not s.active:
-                continue
-            if ("side", s.lane) in self._fresh_wakes:
-                continue  # woke after this window ran: not on device for it
-            s.steps += n
-            s.position += n
-            raw = [int(t) for t in side_ring[s.lane, :n] if t >= 0]
-            allowed = max(0, self.side_max_steps - (len(s.tokens) - s.prompt_len))
-            raw = raw[:allowed]
-            s.tokens.extend(raw)
-            # incremental decode (ISSUE 9 bugfix): same contract as the
-            # rivers — a codepoint split across windows never corrupts
-            # s.text or the thought handed to the merge gate
-            chunk = self._decoder(s.agent_id).feed(raw)
-            s.text += chunk
-            if self.stream_tap is not None and raw:
-                self.stream_tap(s, chunk, raw)
-            all_trig = self.router.feed(s.agent_id, chunk)
-            quiet = quiet and not all_trig
-            trig = [t for t in all_trig if t.kind in ("done", "answer")]
-            generated = len(s.tokens) - s.prompt_len
-            if trig or generated >= self.side_max_steps:
-                # end of this stream: flush the decoder so s.text equals
-                # the one-shot decode bitwise (an incomplete trailing
-                # codepoint replaces, exactly as decode(tokens) would)
-                s.text += self._decoder(s.agent_id).flush()
-                answer = next((t.payload for t in trig if t.kind == "answer"), None)
-                if answer is not None:
-                    thought = answer
-                elif trig:
-                    # feed() spans are absolute offsets into the generated
-                    # stream (== s.text): cut the free-running tokens the
-                    # lane produced between the trigger and this drain
-                    thought = s.text[: trig[0].span[1]]
-                else:
-                    thought = s.text
-                finished.append((s, thought))
+            # 2. streams: append, detect completion (trigger or step budget)
+            finished = []
+            for s in self.sides:
+                if not s.active:
+                    continue
+                if ("side", s.lane) in self._fresh_wakes:
+                    continue  # woke after this window ran: not on device for it
+                s.steps += n
+                s.position += n
+                raw = [int(t) for t in side_ring[s.lane, :n] if t >= 0]
+                allowed = max(0, self.side_max_steps - (len(s.tokens) - s.prompt_len))
+                raw = raw[:allowed]
+                s.tokens.extend(raw)
+                # incremental decode (ISSUE 9 bugfix): same contract as the
+                # rivers — a codepoint split across windows never corrupts
+                # s.text or the thought handed to the merge gate
+                chunk = self._decoder(s.agent_id).feed(raw)
+                s.text += chunk
+                if self.stream_tap is not None and raw:
+                    self.stream_tap(s, chunk, raw)
+                all_trig = self.router.feed(s.agent_id, chunk)
+                quiet = quiet and not all_trig
+                trig = [t for t in all_trig if t.kind in ("done", "answer")]
+                generated = len(s.tokens) - s.prompt_len
+                if trig or generated >= self.side_max_steps:
+                    # end of this stream: flush the decoder so s.text equals
+                    # the one-shot decode bitwise (an incomplete trailing
+                    # codepoint replaces, exactly as decode(tokens) would)
+                    s.text += self._decoder(s.agent_id).flush()
+                    answer = next((t.payload for t in trig if t.kind == "answer"), None)
+                    if answer is not None:
+                        thought = answer
+                    elif trig:
+                        # feed() spans are absolute offsets into the generated
+                        # stream (== s.text): cut the free-running tokens the
+                        # lane produced between the trigger and this drain
+                        thought = s.text[: trig[0].span[1]]
+                    else:
+                        thought = s.text
+                    finished.append((s, thought))
 
-        # 3. merges (free lanes before new spawns claim them)
-        assert not (overlapped and finished), "pipeline gate violated: merge"
-        for s, thought in finished:
-            self._merge_side(s, thought)
-        quiet = quiet and not finished
+            # 3. merges (free lanes before new spawns claim them)
+            assert not (overlapped and finished), "pipeline gate violated: merge"
+            for s, thought in finished:
+                self._merge_side(s, thought)
+            quiet = quiet and not finished
 
-        # 4. river triggers spawn new streams
-        for m in self.mains:
-            if not m.active or m.lane not in main_chunks:
-                continue
-            for tr in self.router.feed(m.agent_id, main_chunks[m.lane]):
-                quiet = False
-                assert not overlapped, "pipeline gate violated: trigger"
-                if tr.kind == "task":
-                    self._spawn_side(m, tr.payload)
+            # 4. river triggers spawn new streams
+            for m in self.mains:
+                if not m.active or m.lane not in main_chunks:
+                    continue
+                for tr in self.router.feed(m.agent_id, main_chunks[m.lane]):
+                    quiet = False
+                    assert not overlapped, "pipeline gate violated: trigger"
+                    if tr.kind == "task":
+                        self._spawn_side(m, tr.payload)
 
-        # 5. window policy: quiet drains earn longer windows, any control
-        # event snaps back to the base window
-        if quiet:
-            self.window.on_quiet_drain()
-        else:
-            self.window.on_event()
-        self._fresh_wakes.clear()  # next window has the woken lanes on device
+            # 5. window policy: quiet drains earn longer windows, any control
+            # event snaps back to the base window
+            if quiet:
+                self.window.on_quiet_drain()
+            else:
+                self.window.on_event()
+            self._fresh_wakes.clear()  # next window has the woken lanes on device
 
     # ------------------------------------------------------------------
     def _free_side_lane(self) -> int:
@@ -1334,58 +1364,63 @@ class CortexEngine:
         return -1
 
     def _spawn_side(self, parent: AgentView, task: str, sampling: SamplingParams | None = None):
-        lane = self._free_side_lane()
-        if lane < 0:
-            return None  # admission policy: drop when streams are saturated
-        new_side_caches = self._jit_spawn(
-            self.state.main_caches, self.state.side_caches, parent.lane, lane
-        )
-        # keep the HEAD on overflow and close the frame: the '[TASK: ... ]'
-        # framing is what conditions the stream; an over-long task loses its
-        # tail, never its framing
-        ids = self.tok.encode(f"[TASK: {task}]")
-        truncated = len(ids) > self.side_prompt_cap
-        if truncated:
-            close = self.tok.encode("]")
-            ids = ids[: self.side_prompt_cap - len(close)] + close
-        padded = ids + [0] * (self.side_prompt_cap - len(ids))
-        self._side_sp[lane] = sampling if sampling is not None else self.side_sampling
-        temp, tk, tp = lane_values(self._side_sp[lane])
-        prompt_a, plen_a, step_a, tok_a, pos_a, act_a, samp_a = self._jit_admit_side(
-            self.state.side_prompt, self.state.side_plen, self.state.side_step,
-            self.state.side_tok, self.state.side_pos, self.state.side_active,
-            self.state.side_samp,
-            lane, jnp.asarray(padded, jnp.int32), len(ids), 0, ids[-1], parent.position,
-            temp, tk, tp,
-        )
-        self.state = dataclasses.replace(
-            self.state, side_caches=new_side_caches, side_prompt=prompt_a,
-            side_plen=plen_a, side_step=step_a, side_tok=tok_a,
-            side_pos=pos_a, side_active=act_a, side_samp=samp_a,
-        )
-        self.stats["aux_dispatches"] += 2
-        s = self.sides[lane]
-        if s.agent_id in self.registry and self.registry.get(s.agent_id).status != REGISTERED:
-            # the classic per-lane identity is still alive (hibernated, or
-            # woken into another lane): mint a fresh one for this spawn
-            s = AgentView(f"side{lane}.{self._agent_seq}", lane, "side")
-            self._agent_seq += 1
-            self.sides[lane] = s
-        s.task, s.text = task, ""
-        self._decoders[s.agent_id] = self.tok.stream_decoder()
-        s.parent_lane = parent.lane
-        s.tokens = list(ids)
-        s.position = parent.position  # continues the stream's positional frame
-        s.active, s.steps = True, 0
-        s.prompt_len = len(ids)
-        self.prism.acquire(s.agent_id)
-        self.registry.register(s.agent_id, "side")
-        rec = self.registry.bind(s.agent_id, lane)
-        rec.bound_tick = self.stats["ticks"]
-        self.history.append(
-            {"event": "spawn", "agent": s.agent_id, "task": task, "task_truncated": truncated}
-        )
-        return s
+        with _span("engine.spawn", parent=parent.agent_id) as sp:
+            lane = self._free_side_lane()
+            if lane < 0:
+                # admission policy: drop when streams are saturated (counted)
+                self.stats["spawns_dropped"] += 1
+                return None
+            new_side_caches = self._jit_spawn(
+                self.state.main_caches, self.state.side_caches, parent.lane, lane
+            )
+            # keep the HEAD on overflow and close the frame: the '[TASK: ... ]'
+            # framing is what conditions the stream; an over-long task loses its
+            # tail, never its framing
+            ids = self.tok.encode(f"[TASK: {task}]")
+            truncated = len(ids) > self.side_prompt_cap
+            if truncated:
+                close = self.tok.encode("]")
+                ids = ids[: self.side_prompt_cap - len(close)] + close
+            padded = ids + [0] * (self.side_prompt_cap - len(ids))
+            self._side_sp[lane] = sampling if sampling is not None else self.side_sampling
+            temp, tk, tp = lane_values(self._side_sp[lane])
+            prompt_a, plen_a, step_a, tok_a, pos_a, act_a, samp_a = self._jit_admit_side(
+                self.state.side_prompt, self.state.side_plen, self.state.side_step,
+                self.state.side_tok, self.state.side_pos, self.state.side_active,
+                self.state.side_samp,
+                lane, jnp.asarray(padded, jnp.int32), len(ids), 0, ids[-1], parent.position,
+                temp, tk, tp,
+            )
+            self.state = dataclasses.replace(
+                self.state, side_caches=new_side_caches, side_prompt=prompt_a,
+                side_plen=plen_a, side_step=step_a, side_tok=tok_a,
+                side_pos=pos_a, side_active=act_a, side_samp=samp_a,
+            )
+            self.stats["aux_dispatches"] += 2
+            self.stats["spawns"] += 1
+            s = self.sides[lane]
+            if s.agent_id in self.registry and self.registry.get(s.agent_id).status != REGISTERED:
+                # the classic per-lane identity is still alive (hibernated, or
+                # woken into another lane): mint a fresh one for this spawn
+                s = AgentView(f"side{lane}.{self._agent_seq}", lane, "side")
+                self._agent_seq += 1
+                self.sides[lane] = s
+            sp.set_metadata(agent=s.agent_id)
+            s.task, s.text = task, ""
+            self._decoders[s.agent_id] = self.tok.stream_decoder()
+            s.parent_lane = parent.lane
+            s.tokens = list(ids)
+            s.position = parent.position  # continues the stream's positional frame
+            s.active, s.steps = True, 0
+            s.prompt_len = len(ids)
+            self.prism.acquire(s.agent_id)
+            self.registry.register(s.agent_id, "side")
+            rec = self.registry.bind(s.agent_id, lane)
+            rec.bound_tick = self.stats["ticks"]
+            self.history.append(
+                {"event": "spawn", "agent": s.agent_id, "task": task, "task_truncated": truncated}
+            )
+            return s
 
     # ------------------------------------------------------------------
     def retire_side(self, lane: int):
@@ -1745,49 +1780,52 @@ class CortexEngine:
         """Window-boundary control plane: idle-ticks demotions, then wake
         commits. ``wait=True`` blocks on outstanding prefetch tickets — used
         when the engine is otherwise idle so a wake-only run makes progress."""
-        did = 0
-        if hibernate_ok:
-            did += self._auto_hibernate()
-        did += self._commit_ready_wakes(wait=wait and bool(self._pending_wakes))
-        if self.admission_hook is not None:
-            # front-end admission control (ISSUE 9): retire finished
-            # request lanes and admit queued work — all boundary ops, so
-            # the pipelined window is never flushed by an admission
-            did += bool(self.admission_hook())
-        return did
+        with _span("engine.boundary"):
+            did = 0
+            if hibernate_ok:
+                did += self._auto_hibernate()
+            did += self._commit_ready_wakes(wait=wait and bool(self._pending_wakes))
+            if self.admission_hook is not None:
+                # front-end admission control (ISSUE 9): retire finished
+                # request lanes and admit queued work — all boundary ops, so
+                # the pipelined window is never flushed by an admission
+                did += bool(self.admission_hook())
+            return did
 
     # ------------------------------------------------------------------
     def _merge_side(self, s: AgentView, thought: str):
-        ids = self.tok.encode(thought)[-self.inject_tokens:]
-        ids = ids + [self.tok.pad_id] * (self.inject_tokens - len(ids))
-        toks = jnp.tile(jnp.asarray(ids, jnp.int32)[None], (self.n_main, 1))
-        vpos = jnp.asarray([m.position for m in self.mains], jnp.int32)  # virtual index
-        lane_mask = jnp.arange(self.n_main) == s.parent_lane
-        new_caches, accept, score = self._jit_merge(
-            self._params, self.state.main_caches, self.state.main_hidden,
-            toks, vpos, lane_mask,
-        )
-        act_a = self._jit_retire_side(self.state.side_active, s.lane)
-        self.state = dataclasses.replace(
-            self.state, main_caches=new_caches, side_active=act_a
-        )
-        self.stats["aux_dispatches"] += 2
-        accepted = bool(np.asarray(accept)[s.parent_lane])  # drain-time sync
-        self.stats["host_syncs"] += 1
-        self.history.append(
-            {
-                "event": "merge",
-                "agent": s.agent_id,
-                "accepted": accepted,
-                "gate_score": float(np.asarray(score)[s.parent_lane]),
-                "thought": thought[:80],
-            }
-        )
-        self.router.reset(s.agent_id)
-        self.prism.release(s.agent_id)
-        self.registry.release(s.agent_id)
-        self._decoders.pop(s.agent_id, None)
-        s.active = False
+        with _span("engine.merge", agent=s.agent_id,
+                   parent=self.mains[s.parent_lane].agent_id):
+            ids = self.tok.encode(thought)[-self.inject_tokens:]
+            ids = ids + [self.tok.pad_id] * (self.inject_tokens - len(ids))
+            toks = jnp.tile(jnp.asarray(ids, jnp.int32)[None], (self.n_main, 1))
+            vpos = jnp.asarray([m.position for m in self.mains], jnp.int32)  # virtual index
+            lane_mask = jnp.arange(self.n_main) == s.parent_lane
+            new_caches, accept, score = self._jit_merge(
+                self._params, self.state.main_caches, self.state.main_hidden,
+                toks, vpos, lane_mask,
+            )
+            act_a = self._jit_retire_side(self.state.side_active, s.lane)
+            self.state = dataclasses.replace(
+                self.state, main_caches=new_caches, side_active=act_a
+            )
+            self.stats["aux_dispatches"] += 2
+            accepted = bool(np.asarray(accept)[s.parent_lane])  # drain-time sync
+            self.stats["host_syncs"] += 1
+            self.history.append(
+                {
+                    "event": "merge",
+                    "agent": s.agent_id,
+                    "accepted": accepted,
+                    "gate_score": float(np.asarray(score)[s.parent_lane]),
+                    "thought": thought[:80],
+                }
+            )
+            self.router.reset(s.agent_id)
+            self.prism.release(s.agent_id)
+            self.registry.release(s.agent_id)
+            self._decoders.pop(s.agent_id, None)
+            s.active = False
 
     # ------------------------------------------------------------------
     def memory_report(self) -> dict:

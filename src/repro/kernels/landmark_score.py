@@ -101,6 +101,7 @@ def landmark_score(q, keys, landmarks=None, *, scale: float | None = None, true_
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="landmark_score",
     )(*args)
     logits = res[0].reshape(B, H, T)
     return (logits, res[1][:, 0]) if with_dist else (logits, None)

@@ -114,6 +114,7 @@ def synapse_attention(
                 jax.ShapeDtypeStruct((BB, T), jnp.float32),
             ],
             interpret=interpret,
+            name="synapse_attention",
         )(qb, kb, vb, validb)
         out = out.reshape(Hkv, B, G, D).swapaxes(1, 0).reshape(B, H, D)
         mass = mass.reshape(Hkv, B, T).sum(axis=0)
@@ -143,5 +144,6 @@ def synapse_attention(
             jax.ShapeDtypeStruct((B, Hkv, 1, T), jnp.float32),
         ],
         interpret=interpret,
+        name="synapse_attention",
     )(qg, keys.reshape(B, T, Hkv * D), values.reshape(B, T, Hkv * D), valid_rows)
     return out.reshape(B, H, D), mass.sum(axis=(1, 2))

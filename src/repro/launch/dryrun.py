@@ -383,7 +383,7 @@ def run_registry(n_registered: int, *, arch: str = "qwen2.5-0.5b",
     agents cost when only ``n_active`` hold device lanes.
 
     Everything is ``eval_shape`` — the per-agent snapshot is the exact
-    pytree `CortexEngine.hibernate` gathers (`_gather_main_lane` over the
+    pytree `CortexEngine.hibernate` gathers (`engine_gather_main` over the
     abstract TickState), so the bytes are the real hibernation payload at
     full `main_capacity`, computed without materializing a single buffer.
     The same math extrapolated to 1M agents is the paper's capacity claim:
@@ -410,7 +410,7 @@ def run_registry(n_registered: int, *, arch: str = "qwen2.5-0.5b",
             main_sampling=greedy, side_sampling=greedy,
         )
     )
-    snap_abs = jax.eval_shape(engine_lib._gather_main_lane, state_abs, 0)
+    snap_abs = jax.eval_shape(engine_lib.engine_gather_main, state_abs, 0)
 
     def abs_bytes(tree) -> int:
         return sum(

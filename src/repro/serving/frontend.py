@@ -34,10 +34,10 @@ Three pieces:
   nothing in flight — so an admission never flushes a window and the
   one-host-sync-per-window / dispatch-count invariants hold unchanged.
   Per-request SLO metrics (TTFT, time-per-output-token, queue wait) and
-  per-tenant aggregates, plus p50/p99 tick latency sampled from commit
-  timestamps, come out of :meth:`ServingFrontend.metrics` and are
-  recorded in BENCH_throughput.json's ``serving`` section by
-  benchmarks/bench_serving.py.
+  per-tenant aggregates, plus p50/p99 tick latency sampled once per
+  :meth:`ServingFrontend.step` chunk, come out of
+  :meth:`ServingFrontend.metrics` and are recorded in BENCH_throughput.json's
+  ``serving`` section by benchmarks/bench_serving.py.
 
 The wire protocol lives in :mod:`repro.serving.transport` (ISSUE 10): an
 HTTP/1.1 + SSE server that maps ``POST /v1/generate`` onto :meth:`submit`
@@ -51,6 +51,8 @@ import math
 import threading
 import time
 from dataclasses import dataclass, field
+
+import jax
 
 from repro.core.engine import CortexEngine
 from repro.serving.sampler import SamplingParams
@@ -399,12 +401,9 @@ class ServingFrontend:
         # any other thread is deferred — flagged on the request and applied
         # at the next admission boundary inside the pump's own loop.
         self._pump_thread: threading.Thread | None = None
-        # tick-latency sampling: (clock, backend step counter) at the last
-        # commit observation; each later commit contributes
-        # (dt / dsteps) samples — amortized per-tick latency as a caller
-        # actually experiences it, pipelining and drain batching included
+        # tick latency: one sample per step() chunk, its wall seconds over
+        # the ticks it advanced, so a stall anywhere in the chunk shows
         self._tick_samples: list[float] = []
-        self._last_mark: tuple[float, int] | None = None
 
         if isinstance(backend, BatchServer):
             self._mode = "batch"
@@ -491,16 +490,21 @@ class ServingFrontend:
         (deferred cancels land at each chunk's admission boundary);
         :meth:`serve` loops it until idle under a total budget."""
         self._pump_thread = threading.current_thread()
+        t0 = self.clock()
         if self._mode == "batch":
             before = self.backend.stats["steps"]
             self.backend.run_until_done(
                 max_ticks=ticks if ticks is not None else 256, pipeline=pipeline
             )
-            return max(0, self.backend.stats["steps"] - before)
-        eng = self.backend
-        before = eng.stats["ticks"]
-        eng.run(ticks if ticks is not None else 8 * eng.sync_every)
-        return max(0, eng.stats["ticks"] - before)
+            advanced = max(0, self.backend.stats["steps"] - before)
+        else:
+            eng = self.backend
+            before = eng.stats["ticks"]
+            eng.run(ticks if ticks is not None else 8 * eng.sync_every)
+            advanced = max(0, eng.stats["ticks"] - before)
+        if advanced:
+            self._tick_samples.append((self.clock() - t0) / advanced)
+        return advanced
 
     def serve(self, *, max_ticks: int = 100_000, pipeline: bool = True) -> None:
         """Pump the backend until every queued/live request completes.
@@ -543,13 +547,6 @@ class ServingFrontend:
         req.stream._close(status, error)
         self.live.pop(req.backend_id, None)
 
-    def _note_progress(self, now: float, steps: int) -> None:
-        if self._last_mark is not None:
-            t0, s0 = self._last_mark
-            if steps > s0 and now > t0:
-                self._tick_samples.append((now - t0) / (steps - s0))
-        self._last_mark = (now, steps)
-
     # -- BatchServer backend -------------------------------------------
     def _admit_batch(self) -> int:
         """Admission-boundary hook: fill free lanes from the fair queue.
@@ -583,7 +580,6 @@ class ServingFrontend:
     def _batch_tap(self, req: FrontRequest):
         def tap(sreq, chunk: str, toks, done: bool):
             now = self.clock()
-            self._note_progress(now, self.backend.stats["steps"])
             if toks:
                 if req.t_first is None:
                     req.t_first = now
@@ -601,32 +597,33 @@ class ServingFrontend:
         retire request lanes whose budget is met (or cancelled), then admit
         queued requests into the freed river lanes. Both are boundary ops —
         the pipelined window is never flushed by an admission."""
-        eng = self.backend
-        did = 0
-        for req in list(self.live.values()):
-            if req.cancel_requested or req.tokens_out >= req.max_new_tokens:
-                try:
-                    self._retire_engine_req(req)
-                except ValueError:
-                    continue  # side streams still target the lane; next boundary
-                did += 1
-        while True:
-            lane = eng._free_main_lane()
-            if lane < 0:
-                break
-            with self._lock:
-                req = self.fq.pop()
-                if req is None:
+        with jax.profiler.TraceAnnotation("fe.admit"):
+            eng = self.backend
+            did = 0
+            for req in list(self.live.values()):
+                if req.cancel_requested or req.tokens_out >= req.max_new_tokens:
+                    try:
+                        self._retire_engine_req(req)
+                    except ValueError:
+                        continue  # side streams still target the lane; next boundary
+                    did += 1
+            while True:
+                lane = eng._free_main_lane()
+                if lane < 0:
                     break
-                aid = f"fe{req.rid}"
-                req.backend_id = aid
-                req.t_admit = self.clock()
-                req.status = "running"
-                self.live[aid] = req
-                eng.submit(req.prompt, lane=lane, sampling=req.sampling,
-                           agent_id=aid)
-            did += 1
-        return did
+                with self._lock:
+                    req = self.fq.pop()
+                    if req is None:
+                        break
+                    aid = f"fe{req.rid}"
+                    req.backend_id = aid
+                    req.t_admit = self.clock()
+                    req.status = "running"
+                    self.live[aid] = req
+                    eng.submit(req.prompt, lane=lane, sampling=req.sampling,
+                               agent_id=aid)
+                did += 1
+            return did
 
     def _retire_engine_req(self, req: FrontRequest) -> None:
         eng = self.backend
@@ -646,7 +643,6 @@ class ServingFrontend:
         if req is None or view.kind != "main":
             return  # side streams and non-frontend agents pass through
         now = self.clock()
-        self._note_progress(now, self.backend.stats["ticks"])
         if toks:
             # guard like _batch_tap (ISSUE 10 bugfix): a drain callback with
             # no tokens for this lane must not stamp TTFT — t_first means "a

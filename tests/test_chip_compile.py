@@ -12,6 +12,7 @@ and every compile stays in this one file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +54,17 @@ def _compile(fn, *args):
     text = compiled.as_text()
     assert "tpu_custom_call" in text  # the Pallas kernel, not a fallback
     return compiled
+
+
+def _kernel_calls(text: str) -> set[str]:
+    """Base names of the Pallas custom calls in compiled HLO text: the names
+    the trace's op events carry (``%synapse_attention.8 = ...``)."""
+    return set(re.findall(
+        r'%([A-Za-z_]+)(?:\.\d+)? = [^\n]*custom_call_target="tpu_custom_call"', text))
+
+
+def _module_name(text: str) -> str:
+    return re.match(r"HloModule ([^,\s]+)", text).group(1)
 
 
 @pytest.mark.parametrize("B,T", [(8, 64 + 64 + 16), (2, 512)])
@@ -111,6 +123,7 @@ def test_macro_window_compiles(one_chip, monkeypatch):
         side_spec=SIDE_SPEC, step_sides=True, use_filters=False, any_greedy=True, n_ticks=8,
     )
     compiled = _compile(window, params, state)
+    assert "synapse_attention" in _kernel_calls(compiled.as_text())
     mem = compiled.memory_analysis()
     # weights (bf16, ~0.99 GB) dominate; the window must fit one 16 GB chip
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
@@ -125,8 +138,22 @@ def test_lane_mesh_spawn_compiles(topo, monkeypatch):
     state = _council_state()
     shardings = shard_lib.shardings_for(shard_lib.tick_state_specs(state, mesh), mesh)
     lane = jax.ShapeDtypeStruct((), jnp.int32, sharding=NamedSharding(mesh, P()))
-    spawn = functools.partial(engine_lib._spawn_lane, CFG, SIDE_SPEC, mesh=mesh)
+    spawn = engine_lib.spawn_program(CFG, SIDE_SPEC, mesh=mesh)
     _compile(
         spawn, _placed(state.main_caches, shardings.main_caches),
         _placed(state.side_caches, shardings.side_caches), lane, lane,
     )
+
+
+def test_spawn_program_compiles_under_its_name(one_chip, monkeypatch):
+    """The engine's spawn program on one chip: the trace finds it by its
+    module name and its landmark kernel by the custom call's name."""
+    monkeypatch.setattr(ops, "INTERPRET", False)
+    place = lambda tree: _placed(tree, jax.tree.map(lambda _: one_chip, tree))
+    state = _council_state()
+    lane = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = _compile(engine_lib.spawn_program(CFG, SIDE_SPEC),
+                        place(state.main_caches), place(state.side_caches), lane, lane)
+    text = compiled.as_text()
+    assert _module_name(text) == "jit_engine_spawn"
+    assert _kernel_calls(text) == {"landmark_score"}

@@ -204,6 +204,43 @@ def test_batch_stream_bitwise_and_slo_metrics(setup):
     assert m["fairness"]["admission_rounds"] == 4
 
 
+@pytest.mark.parametrize("backend", ["batch", "engine"])
+def test_tick_latency_samples_each_step_chunk(setup, backend):
+    # one sample per step() chunk: its wall seconds over the ticks it
+    # advanced, so one slow chunk lifts p99 above p50 whatever its commits
+    cfg, params = setup
+    now = [0.0]
+    if backend == "batch":
+        fe = _frontend(cfg, params, clock=lambda: now[0])
+        name = "run_until_done"
+    else:
+        eng = CortexEngine(
+            Prism(params, cfg), ByteTokenizer(cfg.vocab_size), n_main=2, max_side=2,
+            main_capacity=128, sampling=SamplingParams(greedy=True), sync_every=4,
+        )
+        fe = ServingFrontend(eng, clock=lambda: now[0])
+        name = "run"
+    inner, calls = getattr(fe.backend, name), []
+
+    def timed(*a, **kw):
+        out = inner(*a, **kw)
+        calls.append(1)
+        now[0] += 1.0 if len(calls) == 2 else 0.01  # the second chunk stalls
+        return out
+
+    setattr(fe.backend, name, timed)
+    fe.submit("slow chunk one", max_new_tokens=24)
+    fe.submit("slow chunk two", max_new_tokens=24)
+    advanced = []
+    while fe.pending():
+        advanced.append(fe.step(4))
+    assert len(advanced) >= 3 and all(advanced)
+    lat = fe.metrics()["tick_latency_s"]
+    assert lat["n"] == len(advanced)
+    assert lat["p99"] == pytest.approx(1.0 / advanced[1])
+    assert lat["p50"] == pytest.approx(0.01 / 4) and lat["p99"] > lat["p50"]
+
+
 def test_batch_stream_consumed_from_other_thread(setup):
     cfg, params = setup
     fe = _frontend(cfg, params)
@@ -253,7 +290,6 @@ def test_engine_tap_records_ttft_only_with_tokens(setup):
 
     cfg, params = setup
     fe = _frontend(cfg, params)
-    fe.backend.stats["ticks"] = 0  # the engine-style counter the tap samples
     req = _req(1, "t")
     fe.requests[1] = req
     fe.live["aid"] = req
