@@ -95,7 +95,8 @@ def _max_err(a, b) -> float:
 def check_kernels(cfg, seed: int) -> None:
     """Both kernels against the f32 oracle in ``kernels/ref.py``, at the
     model's head widths: the side set (landmarks + window + inject slots)
-    and the river's cache."""
+    and the river's cache, then the side pass's in-place attend over the
+    three pieces at the council's 256 lanes and at qwen3-4b's widths."""
     import jax
     import jax.numpy as jnp
 
@@ -103,7 +104,7 @@ def check_kernels(cfg, seed: int) -> None:
 
     H, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     bf = jnp.bfloat16
-    ks = iter(jax.random.split(jax.random.key(seed), 16))
+    ks = iter(jax.random.split(jax.random.key(seed), 64))
     normal = lambda shape: jax.random.normal(next(ks), shape).astype(bf)
     log(f"kernels: tolerance max|err| <= {TOL_BF16_OUT} (bf16 outputs), "
         f"<= {TOL_F32} (f32 mass/density/distance)")
@@ -140,6 +141,38 @@ def check_kernels(cfg, seed: int) -> None:
         else:
             assert dist is None
         log(msg)
+
+    # the side pass's attend, its three pieces read in place from stacks of
+    # every layer's lane-dense rows, partly filled: the council's 256 lanes
+    # at this model's widths, and 64 lanes at qwen3-4b's (8 kv heads of 128,
+    # the widest row, 8 lanes a grid step)
+    from repro.configs import get_config
+    from repro.kernels import synapse_attention as sa
+
+    sizes = (64, 64, 16)
+    for c, B in ((cfg, 256), (get_config("qwen3-4b"), 64)):
+        H, Hkv, D, NL = c.n_heads, c.n_kv_heads, c.d_head, c.n_layers
+        layer = NL - 1
+        assert sa.fits_in_place(B, H, sizes, Hkv * D, 2), (c.name, "must take the in-place path")
+        q = normal((B, H, D))
+        stacks = [(normal((NL, B, T, Hkv * D)), normal((NL, B, T, Hkv * D))) for T in sizes]
+        fills = [jax.random.randint(next(ks), (B,), 0, T + 1) for T in sizes]
+        fills[1] = jnp.maximum(fills[1], 1)  # the window always holds the new token
+        valids = [jnp.arange(T)[None, :] < n[:, None] for T, n in zip(sizes, fills)]
+        out, masses = jax.jit(
+            lambda q, p, m, i: ops.attend_pieces(q, p, m, 1.0 / D ** 0.5, layer=i))(
+            q, stacks, valids, jnp.int32(layer))
+        pieces = [(k[layer], v[layer]) for k, v in stacks]
+        heads = lambda a: a.reshape(B, -1, Hkv, D)
+        with jax.default_matmul_precision("highest"):
+            out_r, mass_r = ref.synapse_attention_ref(
+                q, jnp.concatenate([heads(k) for k, _ in pieces], 1),
+                jnp.concatenate([heads(v) for _, v in pieces], 1), jnp.concatenate(valids, 1))
+        e_out, e_mass = _max_err(out, out_r), _max_err(jnp.concatenate(masses, 1), mass_r)
+        log(f"kernels: synapse_attention in place, {c.name} widths, pieces {sizes} B={B}, "
+            f"layer {layer} of {NL}: max|out err| {e_out:.3g}, max|mass err| {e_mass:.3g}")
+        assert e_out <= TOL_BF16_OUT and e_mass <= TOL_F32, ("pieces", c.name, e_out, e_mass)
+        del stacks, pieces
 
 
 def _count(events, kind: str) -> int:

@@ -104,8 +104,9 @@ def inject_synapse(main: cache_lib.SynapseCache, thought: cache_lib.FullCache, a
     J = main.inj_k.shape[2]
     T = thought.k.shape[2]
     take = min(T, J)
-    th_k = thought.k[:, :, -take:]
-    th_v = thought.v[:, :, -take:]
+    lane_rows = lambda a: a.reshape(a.shape[:3] + (-1,))  # the synapse's [.., Hkv*D] rows
+    th_k = lane_rows(thought.k[:, :, -take:])
+    th_v = lane_rows(thought.v[:, :, -take:])
     th_pos = thought.pos[:, :, -take:]
     start = jnp.minimum(main.inj_count[0], J - take)  # [B]
     new_k = _append_lanes(main.inj_k, th_k, start, axis=1)

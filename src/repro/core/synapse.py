@@ -23,6 +23,8 @@ Both operate per layer, vectorized over the batch/agent axis.
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -41,10 +43,10 @@ class SynapsePolicy:
     alpha: float = 0.5        # density vs coverage blend
     score_ema: float = 0.99   # per-step decay of accumulated attention mass
     coverage_cap: float = 4.0 # maxmin distances saturate here (normalized units)
-    # decode attend implementation: "pallas" = fused kernels.ops.synapse_attention
-    # over the concatenated [landmarks; window; inject] set (single device,
-    # interpret mode on CPU); "piece" = synapse_sharded.piece_attend (the
-    # multi-chip flash-decode). A live shard axis always forces "piece".
+    # decode attend implementation: "pallas" = one fused kernels.ops attend
+    # over the [landmarks; window; inject] pieces (single device, interpret
+    # mode on CPU); "piece" = synapse_sharded.piece_attend (the multi-chip
+    # flash-decode). A live shard axis always forces "piece".
     attend_impl: str = "pallas"
     # mesh axis the synapse token dims are sharded over (None = local). The
     # engine-owned replacement for the old synapse_sharded.set_shard_axis
@@ -61,6 +63,14 @@ class SynapsePolicy:
 def _pool_heads(k):
     """[..., Hkv, D] -> [..., D] mean over kv heads (coverage geometry)."""
     return k.astype(jnp.float32).mean(axis=-2)
+
+
+def _pool_rows(k, d: int):
+    """Lane-dense [..., Hkv*D] -> [..., D] mean over kv heads, summed over
+    the heads' column slices (no [..., Hkv, D] relayout of the rows)."""
+    hkv = k.shape[-1] // d
+    kf = k.astype(jnp.float32)
+    return functools.reduce(jnp.add, [kf[..., j * d:(j + 1) * d] for j in range(hkv)]) / hkv
 
 
 def _normed_dist(a, b):
@@ -93,14 +103,15 @@ def kernel_density(q, keys, valid):
     return density
 
 
-def _attend(q1, pieces, valids, scale, policy: SynapsePolicy):
-    """Attend over [landmarks; window; inject] k/v pieces — delegates to
-    :func:`repro.kernels.ops.synapse_attend`, which routes on the policy
-    (fused Pallas attend vs the token-sharded flash-decode piece_attend).
+def _attend(q1, pieces, valids, scale, policy: SynapsePolicy, layer):
+    """Attend over layer ``layer`` of the stacked [landmarks; window;
+    inject] k/v pieces — delegates to :func:`repro.kernels.ops.synapse_attend`,
+    which routes on the policy (fused Pallas attend vs the token-sharded
+    flash-decode piece_attend).
     Returns (out [B,H,D], masses — one [B,T_i] per piece)."""
     from repro.kernels import ops
 
-    return ops.synapse_attend(q1, pieces, valids, scale=scale, policy=policy)
+    return ops.synapse_attend(q1, pieces, valids, scale=scale, policy=policy, layer=layer)
 
 
 # ---------------------------------------------------------------------------
@@ -165,7 +176,8 @@ def compress(
     idx = jnp.take_along_axis(idx, order, axis=1)
     score = jnp.take_along_axis(score, order, axis=1)
 
-    gather = lambda a: jnp.take_along_axis(a, idx[:, :, None, None], axis=1)
+    gather = lambda a: jnp.take_along_axis(a, idx[:, :, None, None], axis=1).reshape(
+        B, n_landmarks, -1)  # lane-dense [B, K, Hkv*D]
     syn = cache_lib.init_synapse_cache(
         cfg, B, n_landmarks, window, n_inject, dtype=cache.k.dtype
     )
@@ -199,14 +211,25 @@ def synapse_decode(
     cache: cache_lib.SynapseCache,
     positions,  # [B] (or [B,3] mrope)
     policy: SynapsePolicy = SynapsePolicy(),
+    *,
+    layer,
 ):
     """One decode step: attend over [landmarks; window; inject slots], write
     the new token into the window ring, graduate/evict on overflow.
 
+    ``cache`` holds every layer's synapse ([NL, B, ...] leaves: the decode
+    scan carries it) and the step reads and writes layer ``layer`` (an int
+    or a traced index): K/V rows are written into the stack in place and
+    the attend reads the stacked pieces, so no per-layer copy of the key
+    set is made.
+
     Returns (y [B,1,dm], new_cache, stats dict).
     """
+    at = lambda a: a[layer]  # this layer's slice of a small leaf
     B = x.shape[0]
     K, W, J = cache.n_landmarks, cache.window, cache.n_inject
+    win_count, lm_count, lm_score = at(cache.win_count), at(cache.lm_count), at(cache.lm_score)
+    win_pos, win_score = at(cache.win_pos), at(cache.win_score)
     q, k, v = _project_qkv(attn_params, cfg, x)
     if cfg.rope_kind == "mrope":
         q = _rotate(cfg, q, positions[..., None])
@@ -216,22 +239,24 @@ def synapse_decode(
         q = _rotate(cfg, q, positions[..., None])
         k = _rotate(cfg, k, positions[..., None])
         pos_scalar = positions
-    q1, k1, v1 = q[:, 0], k[:, 0], v[:, 0]
+    D = q.shape[-1]
+    # the synapse stores K/V lane-dense: one [Hkv*D] row per slot
+    q1, k1, v1 = q[:, 0], k[:, 0].reshape(B, -1), v[:, 0].reshape(B, -1)
 
     # ---- 1. graduation: the slot the new token will overwrite ----
     # one-hot reads/writes shard over the token dim without scatter
     # (EXPERIMENTS.md §Perf: SPMD 'involuntary full rematerialization').
-    slot = cache.win_count % W  # [B]
-    win_full = cache.win_count >= W
-    grad_k = sharded.onehot_read(cache.win_k, slot)      # [B, Hkv, D]
-    grad_v = sharded.onehot_read(cache.win_v, slot)
-    grad_pos = sharded.onehot_read(cache.win_pos, slot)
-    grad_score = sharded.onehot_read(cache.win_score, slot)
+    slot = win_count % W  # [B]
+    win_full = win_count >= W
+    grad_k = sharded.onehot_read(cache.win_k, slot, layer=layer)  # [B, Hkv*D]
+    grad_v = sharded.onehot_read(cache.win_v, slot, layer=layer)
+    grad_pos = sharded.onehot_read(win_pos, slot)
+    grad_score = sharded.onehot_read(win_score, slot)
 
-    pooled_lm = _pool_heads(cache.lm_k)                   # [B, K, D]
-    grad_pooled = _pool_heads(grad_k[:, None])[:, 0]      # [B, D]
+    pooled_lm = _pool_rows(at(cache.lm_k), D)             # [B, K, D]
+    grad_pooled = _pool_rows(grad_k, D)                   # [B, D]
     dist = _normed_dist(pooled_lm, grad_pooled)           # [B, K]
-    lm_slot_valid = jnp.arange(K)[None, :] < cache.lm_count[:, None]
+    lm_slot_valid = jnp.arange(K)[None, :] < lm_count[:, None]
     min_dist = jnp.min(jnp.where(lm_slot_valid, dist, jnp.inf), axis=-1)
     cov = jnp.minimum(jnp.where(jnp.isfinite(min_dist), min_dist, policy.coverage_cap), policy.coverage_cap) / policy.coverage_cap
 
@@ -242,13 +267,13 @@ def synapse_decode(
     # rates; the coverage bonus is scaled into rate units by the mean
     # landmark rate so the hybrid blend stays dimensionally consistent.
     one_minus_ema = max(1.0 - policy.score_ema, 1e-6)
-    resid = jnp.minimum(jnp.maximum(cache.win_count.astype(jnp.float32), 1.0), float(W))
+    resid = jnp.minimum(jnp.maximum(win_count.astype(jnp.float32), 1.0), float(W))
     grad_rate = grad_score / resid
-    lm_rate = cache.lm_score * one_minus_ema                      # [B, K]
+    lm_rate = lm_score * one_minus_ema                            # [B, K]
     lm_rate_masked = jnp.where(lm_slot_valid, lm_rate, jnp.inf)
     min_lm_rate = jnp.min(lm_rate_masked, axis=-1)
     mean_lm_rate = jnp.sum(jnp.where(lm_slot_valid, lm_rate, 0.0), axis=-1) / jnp.maximum(
-        cache.lm_count.astype(jnp.float32), 1.0
+        lm_count.astype(jnp.float32), 1.0
     )
     hybrid_rate = policy.alpha * grad_rate + (1 - policy.alpha) * cov * jnp.maximum(
         mean_lm_rate, grad_rate
@@ -256,34 +281,35 @@ def synapse_decode(
 
     # candidate landmark slot: first empty, else argmin rate
     evict_slot = jnp.where(
-        cache.lm_count < K,
-        cache.lm_count,
+        lm_count < K,
+        lm_count,
         jnp.argmin(jnp.where(lm_slot_valid, lm_rate, jnp.inf), axis=-1),
     )
-    promote = win_full & ((cache.lm_count < K) | (hybrid_rate > min_lm_rate))
+    promote = win_full & ((lm_count < K) | (hybrid_rate > min_lm_rate))
 
-    lm_k = sharded.onehot_write(cache.lm_k, evict_slot, grad_k, mask=promote)
-    lm_v = sharded.onehot_write(cache.lm_v, evict_slot, grad_v, mask=promote)
-    lm_pos = sharded.onehot_write(cache.lm_pos, evict_slot, grad_pos, mask=promote)
+    lm_k = sharded.onehot_write(cache.lm_k, evict_slot, grad_k, mask=promote, layer=layer)
+    lm_v = sharded.onehot_write(cache.lm_v, evict_slot, grad_v, mask=promote, layer=layer)
+    lm_pos = sharded.onehot_write(at(cache.lm_pos), evict_slot, grad_pos, mask=promote)
     # store back in EMA-steady units so future comparisons stay consistent
     lm_score = sharded.onehot_write(
-        cache.lm_score, evict_slot, hybrid_rate / one_minus_ema, mask=promote
+        lm_score, evict_slot, hybrid_rate / one_minus_ema, mask=promote
     )
-    lm_count = jnp.where(promote, jnp.minimum(cache.lm_count + 1, K), cache.lm_count)
+    lm_count = jnp.where(promote, jnp.minimum(lm_count + 1, K), lm_count)
 
     # ---- 2. write the new token into the ring ----
-    win_k = sharded.onehot_write(cache.win_k, slot, k1)
-    win_v = sharded.onehot_write(cache.win_v, slot, v1)
-    win_pos = sharded.onehot_write(cache.win_pos, slot, pos_scalar)
-    win_score = sharded.onehot_write(cache.win_score, slot, jnp.zeros((B,), jnp.float32))
+    win_k = sharded.onehot_write(cache.win_k, slot, k1, layer=layer)
+    win_v = sharded.onehot_write(cache.win_v, slot, v1, layer=layer)
+    win_pos = sharded.onehot_write(win_pos, slot, pos_scalar)
+    win_score = sharded.onehot_write(win_score, slot, jnp.zeros((B,), jnp.float32))
 
     # ---- 3. attend over [landmarks; window; inject] ----
-    # default: one fused Pallas pass over the concatenated token set (the
-    # buffers leave HBM exactly once per step); sharded runs flash-decode
-    # over token-sharded pieces, crossing chips with [B,Hkv,G] stats only.
+    # default: one fused Pallas pass over the three pieces, read in place
+    # from the stacks (the buffers leave HBM exactly once per step);
+    # sharded runs flash-decode over token-sharded pieces, crossing chips
+    # with [B,Hkv,G] stats only.
     lm_valid = jnp.arange(K)[None, :] < lm_count[:, None]
-    win_valid = jnp.arange(W)[None, :] < jnp.minimum(cache.win_count + 1, W)[:, None]
-    inj_valid = jnp.arange(J)[None, :] < cache.inj_count[:, None]
+    win_valid = jnp.arange(W)[None, :] < jnp.minimum(win_count + 1, W)[:, None]
+    inj_valid = jnp.arange(J)[None, :] < at(cache.inj_count)[:, None]
     scale = 1.0 / (q1.shape[-1] ** 0.5)
     out, masses = _attend(
         q1,
@@ -291,23 +317,24 @@ def synapse_decode(
         [lm_valid, win_valid, inj_valid],
         scale,
         policy,
+        layer,
     )
     y = out.reshape(B, -1) @ attn_params["wo"]
 
     # ---- 4. accumulate attention mass (density statistic) ----
     ema = policy.score_ema
-    lm_score = lm_score * ema + masses[0]
-    win_score = win_score * ema + masses[1]
-    mass = jnp.concatenate(masses, axis=1)
-
-    new_cache = cache_lib.SynapseCache(
-        lm_k=lm_k, lm_v=lm_v, lm_pos=lm_pos, lm_score=lm_score, lm_count=lm_count,
-        win_k=win_k, win_v=win_v, win_pos=win_pos, win_score=win_score,
-        inj_k=cache.inj_k, inj_v=cache.inj_v, inj_pos=cache.inj_pos,
-        inj_count=cache.inj_count, win_count=cache.win_count + 1,
-        length=cache.length + 1,
+    put = lambda stack, val: stack.at[layer].set(val)
+    new_cache = dataclasses.replace(
+        cache, lm_k=lm_k, lm_v=lm_v, win_k=win_k, win_v=win_v,
+        lm_pos=put(cache.lm_pos, lm_pos),
+        lm_score=put(cache.lm_score, lm_score * ema + masses[0]),
+        lm_count=put(cache.lm_count, lm_count),
+        win_pos=put(cache.win_pos, win_pos),
+        win_score=put(cache.win_score, win_score * ema + masses[1]),
+        win_count=put(cache.win_count, win_count + 1),
+        length=put(cache.length, at(cache.length) + 1),
     )
-    stats = {"promoted": promote, "attn_mass_landmarks": mass[:, :K].sum(-1)}
+    stats = {"promoted": promote, "attn_mass_landmarks": masses[0].sum(-1)}
     return y[:, None, :], new_cache, stats
 
 

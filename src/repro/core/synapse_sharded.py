@@ -84,23 +84,29 @@ def _resolve(ctx: ShardContext | None) -> ShardContext:
     return _CTX.get() if ctx is None else ctx
 
 
-def onehot_write(buf, slot, new, mask=None, *, ctx: ShardContext | None = None):
+def onehot_write(buf, slot, new, mask=None, *, layer=None, ctx: ShardContext | None = None):
     """buf [B,T,...] <- new [B,...] at per-lane `slot`, via one-hot select.
+    With ``layer``, buf is a stack [NL,B,T,...] of per-layer buffers and
+    the rows land in layer ``layer`` of it.
 
     Single-device (no shard axis — the engine hot path): a plain per-lane
     scatter, bitwise-identical to the one-hot select for in-bounds slots
     (0 <= slot < T, which every caller guarantees — the one-hot form drops
     out-of-range slots while a scatter would clamp) but without
     materializing [B,T]-shaped masks for every ring write of every layer
-    of every virtual tick."""
+    of every virtual tick. Into a stack the scatter is in place: no copy
+    of the layer's buffer is made."""
     if _resolve(ctx).axis is None:
-        lane = jnp.arange(buf.shape[0])
+        lane = jnp.arange(new.shape[0])
+        at = (lane, slot) if layer is None else (layer, lane, slot)
         val = new.astype(buf.dtype)
         if mask is not None:
-            cur = buf[lane, slot]
+            cur = buf[at]
             m = mask.reshape(mask.shape + (1,) * (val.ndim - 1))
             val = jnp.where(m, val, cur)
-        return buf.at[lane, slot].set(val)
+        return buf.at[at].set(val)
+    if layer is not None:
+        return buf.at[layer].set(onehot_write(buf[layer], slot, new, mask, ctx=ctx))
     T = buf.shape[1]
     oh = jax.nn.one_hot(slot, T, dtype=bool)  # [B, T]
     if mask is not None:
@@ -109,46 +115,47 @@ def onehot_write(buf, slot, new, mask=None, *, ctx: ShardContext | None = None):
     return jnp.where(oh, new[:, None].astype(buf.dtype), buf)
 
 
-def onehot_read(buf, slot, *, ctx: ShardContext | None = None):
+def onehot_read(buf, slot, *, layer=None, ctx: ShardContext | None = None):
     """buf [B,T,...] -> [B,...] at per-lane slot (one-hot contraction; plain
     per-lane gather when no shard axis is live — exact for f32/int32 and
-    in-bounds slots, so the two formulations are interchangeable there)."""
+    in-bounds slots, so the two formulations are interchangeable there).
+    With ``layer``, buf is a stack [NL,B,T,...] read at layer ``layer``."""
     if _resolve(ctx).axis is None:
-        return buf[jnp.arange(buf.shape[0]), slot]
+        lane = jnp.arange(slot.shape[0])
+        return buf[lane, slot] if layer is None else buf[layer, lane, slot]
+    if layer is not None:
+        buf = buf[layer]
     T = buf.shape[1]
     oh = jax.nn.one_hot(slot, T, dtype=jnp.float32)
     out = jnp.einsum("bt,bt...->b...", oh, buf.astype(jnp.float32))
     return out.astype(buf.dtype)
 
 
-def piece_attend(q, pieces, valids, scale, *, ctx: ShardContext | None = None):
+def piece_attend(q, pieces, valids, scale, *, layer, ctx: ShardContext | None = None):
     """Flash-decode attend over token-sharded (k, v) pieces.
 
-    q: [B,H,D]; pieces: [(k_i, v_i)] with k_i/v_i [B,T_i,Hkv,D] sharded on
-    T_i over ``ctx.axis``; valids: [(B,T_i)] bools.
+    q: [B,H,D]; pieces: [(k_i, v_i)] with k_i/v_i the stacks
+    [NL,B,T_i,Hkv*D] of every layer's lane-dense rows (the synapse cache's
+    layout), sharded on T_i over ``ctx.axis`` and attended at layer
+    ``layer``; valids: [(B,T_i)] bools.
     Returns (out [B,H,D], masses [(B,T_i)] — per-key probability mass).
 
     No shard axis (the lane-sharded engine's per-shard body, and the
-    single-device fallback): ONE fused ``kernels.ops.synapse_attention``
-    call over the concatenated set — the exact computation of the default
-    "pallas" attend, so lane-sharded and single-device engines stay BITWISE
-    identical (tests/test_lane_sharded.py pins this).
+    single-device fallback): ``kernels.ops.attend_pieces``, the exact
+    computation of the default "pallas" attend, so lane-sharded and
+    single-device engines stay BITWISE identical
+    (tests/test_lane_sharded.py pins this).
     """
     axis = _resolve(ctx).axis
-    B, H, D = q.shape
-    Hkv = pieces[0][0].shape[2]
-    G = H // Hkv
-    sizes = [k.shape[1] for k, _ in pieces]
-
     if axis is None:
         from repro.kernels import ops  # deferred: keeps core importable alone
 
-        k_all = jnp.concatenate([k for k, _ in pieces], axis=1)
-        v_all = jnp.concatenate([v for _, v in pieces], axis=1)
-        valid_all = jnp.concatenate(list(valids), axis=1)
-        out, mass = ops.synapse_attention(q, k_all, v_all, valid_all, scale=scale)
-        splits = list(np.cumsum(sizes))[:-1]
-        return out, list(jnp.split(mass, splits, axis=1))
+        return ops.attend_pieces(q, pieces, valids, scale, layer=layer)
+    B, H, D = q.shape
+    heads = lambda a: a[layer].reshape(a.shape[1:3] + (-1, D))
+    pieces = [(heads(k), heads(v)) for k, v in pieces]
+    Hkv = pieces[0][0].shape[2]
+    G = H // Hkv
 
     def body(q, *flat):
         n = len(pieces)

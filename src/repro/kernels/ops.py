@@ -1,9 +1,15 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Handles padding to TPU tile alignment (T, D multiples of 128), dtype policy,
-and the interpret-mode switch (CPU container: interpret=True executes the
-kernel body in Python for correctness; on TPU the same code compiles to
-Mosaic). ``INTERPRET`` auto-detects the backend.
+Handles tile alignment, dtype policy, and the interpret-mode switch (CPU
+container: interpret=True executes the kernel body in Python for
+correctness; on TPU the same code compiles to Mosaic). ``INTERPRET``
+auto-detects the backend.
+
+Which path pads: :func:`attend_pieces`, the side pass's attend, reads the
+synapse pieces in place from the layer stacks when they tile (no copy, no
+pad, no concatenate); :func:`synapse_attention` on one pre-joined key set
+pads T and D to multiples of 128, as does :func:`landmark_score` on the
+river's cache.
 """
 from __future__ import annotations
 
@@ -74,7 +80,7 @@ def synapse_attention(q, keys, values, valid, *, scale: float | None = None, int
     return out[:, :, :D], mass[:, :T]
 
 
-def synapse_attend(q, pieces, valids, *, scale: float | None = None, policy=None):
+def synapse_attend(q, pieces, valids, *, layer, scale: float | None = None, policy=None):
     """Policy-routed attend over [landmarks; window; inject] k/v pieces —
     the single entry the synapse decode calls, threading the engine-owned
     ``SynapsePolicy`` (no module globals).
@@ -82,10 +88,11 @@ def synapse_attend(q, pieces, valids, *, scale: float | None = None, policy=None
     Routing: a live token-shard axis — from ``policy.shard_axis`` or an
     enclosing :func:`repro.core.synapse_sharded.token_sharding` scope — or
     ``policy.attend_impl == "piece"`` selects the flash-decode
-    ``piece_attend`` path; otherwise ONE fused :func:`synapse_attention`
-    over the concatenated token set. Both paths reduce to the identical
-    fused computation when no axis is live, so the choice never perturbs
-    token streams (the lane-sharded engine's bitwise-parity contract).
+    ``piece_attend`` path; otherwise :func:`attend_pieces` on this device.
+    ``piece_attend`` with no live axis calls :func:`attend_pieces` too, so
+    the choice never perturbs token streams (the lane-sharded engine's
+    bitwise-parity contract). The pieces are stacks [NL,B,T_i,Hkv*D] of
+    every layer's rows, and layer ``layer`` of them is attended.
     Returns (out [B,H,D], masses — one [B,T_i] per piece).
     """
     from repro.core import synapse_sharded as sharded  # deferred: no cycle
@@ -97,10 +104,33 @@ def synapse_attend(q, pieces, valids, *, scale: float | None = None, policy=None
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     if ctx.axis is not None or getattr(policy, "attend_impl", "pallas") == "piece":
-        return sharded.piece_attend(q, pieces, valids, scale, ctx=ctx)
-    sizes = [k.shape[1] for k, _ in pieces]
-    k_all = jnp.concatenate([k for k, _ in pieces], axis=1)
-    v_all = jnp.concatenate([v for _, v in pieces], axis=1)
+        return sharded.piece_attend(q, pieces, valids, scale, ctx=ctx, layer=layer)
+    return attend_pieces(q, pieces, valids, scale, layer=layer)
+
+
+def attend_pieces(q, pieces, valids, scale: float, *, layer):
+    """ONE fused attend over k/v pieces on one device. q [B,H,D]; each
+    piece is the stack [NL,B,T_i,Hkv*D] of every layer's lane-dense rows
+    (the synapse cache's layout), attended at layer ``layer`` (an int or a
+    traced index).
+
+    On the chip, pieces that tile (:func:`synapse_attention.fits_in_place`:
+    Hkv*D a multiple of 128, every T_i a multiple of 16) go to the
+    piece-wise kernel, read in place from the stacks: no copy of the
+    layer, no concatenation, no padding, one call. Other shapes are joined
+    along T and take the padded :func:`synapse_attention`. In interpret
+    mode (CPU) the pieces are joined too, and decode sizes run the jnp
+    oracle.
+    Returns (out [B,H,D], masses — one [B,T_i] per piece).
+    """
+    B, H, D = q.shape
+    sizes = [k.shape[2] for k, _ in pieces]
+    width = pieces[0][0].shape[3]
+    if not INTERPRET and _sa.fits_in_place(B, H, sizes, width, pieces[0][0].dtype.itemsize):
+        return _sa.synapse_attention_pieces(q, pieces, valids, layer, scale=scale)
+    heads = lambda a: a[layer].reshape(a.shape[1:3] + (width // D, D))
+    k_all = jnp.concatenate([heads(k) for k, _ in pieces], axis=1)
+    v_all = jnp.concatenate([heads(v) for _, v in pieces], axis=1)
     valid_all = jnp.concatenate(list(valids), axis=1)
     out, mass = synapse_attention(q, k_all, v_all, valid_all, scale=scale)
     splits = [sum(sizes[: i + 1]) for i in range(len(sizes) - 1)]

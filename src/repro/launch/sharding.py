@@ -242,7 +242,8 @@ def batch_specs(batch_abstract, cfg: ModelConfig, mesh: Mesh):
 
 
 def cache_specs(caches_abstract, cfg: ModelConfig, mesh: Mesh, *, synapse_token_shard: bool = True):
-    """Stacked caches [L, B, T, Hkv, D] (or state trees [L, B, ...]).
+    """Stacked caches [L, B, T, Hkv, D], synapse K/V [L, B, T, Hkv*D] (or
+    state trees [L, B, ...]).
 
     Batch over (pod, data). For 4D+ cache leaves: try kv-heads over "model";
     if not divisible the _fit fallback replicates, and instead the token/
@@ -263,11 +264,17 @@ def cache_specs(caches_abstract, cfg: ModelConfig, mesh: Mesh, *, synapse_token_
         is_synapse_buf = any(
             str(n).startswith(("lm_", "win_", "inj_")) for n in names
         )
-        if is_synapse_buf and not synapse_token_shard:
-            axes = [None, dp] + [None] * max(nd - 2, 0)
-            if nd == 5 and shape[3] % tp_size == 0:
+        if is_synapse_buf and nd == 4:
+            # lane-dense K/V [L, B, T, Hkv*D]: whole kv heads over model when
+            # they divide, else (token shard) the token dim, else replicate
+            axes = [None, dp, None, None]
+            if cfg.n_kv_heads % tp_size == 0:
                 axes[3] = tp
-            return _spec(mesh, shape, axes[:nd])
+            elif synapse_token_shard and shape[2] % tp_size == 0:
+                axes[2] = tp
+            return _spec(mesh, shape, axes)
+        if is_synapse_buf and not synapse_token_shard:
+            return _spec(mesh, shape, ([None, dp] + [None] * max(nd - 2, 0))[:nd])
         if nd <= 1:
             return P()
         if nd == 2:  # [L, B] lengths/counts

@@ -47,36 +47,40 @@ class FullCache:
 @_register
 @dataclass
 class SynapseCache:
+    # K/V are stored lane-dense, [B, T_i, Hkv*D]: the kv heads side by side
+    # in one row, the layout the side pass's attend kernel reads in place.
     # landmark region (the "Topological Synapse")
-    lm_k: jax.Array      # [B, K, Hkv, D]
-    lm_v: jax.Array      # [B, K, Hkv, D]
+    lm_k: jax.Array      # [B, K, Hkv*D]
+    lm_v: jax.Array      # [B, K, Hkv*D]
     lm_pos: jax.Array    # [B, K] int32
     lm_score: jax.Array  # [B, K] f32 — accumulated hybrid density-coverage score
     lm_count: jax.Array  # [B] int32 — populated landmark slots
     # recent window ring
-    win_k: jax.Array     # [B, W, Hkv, D]
-    win_v: jax.Array     # [B, W, Hkv, D]
+    win_k: jax.Array     # [B, W, Hkv*D]
+    win_v: jax.Array     # [B, W, Hkv*D]
     win_pos: jax.Array   # [B, W] int32
     win_score: jax.Array # [B, W] f32 — attention mass accumulated while resident
     # referential injection slots (paper §3.6)
-    inj_k: jax.Array     # [B, J, Hkv, D]
-    inj_v: jax.Array     # [B, J, Hkv, D]
+    inj_k: jax.Array     # [B, J, Hkv*D]
+    inj_v: jax.Array     # [B, J, Hkv*D]
     inj_pos: jax.Array   # [B, J] int32
     inj_count: jax.Array # [B] int32
     win_count: jax.Array # [B] int32 — tokens written into the ring (fill state)
     length: jax.Array    # [B] int32 — total stream tokens seen
 
+    # slot counts read off the K rows' second-minor dim, so they hold for
+    # one layer's synapse and for the stack of every layer's alike
     @property
     def n_landmarks(self) -> int:
-        return self.lm_k.shape[1]
+        return self.lm_k.shape[-2]
 
     @property
     def window(self) -> int:
-        return self.win_k.shape[1]
+        return self.win_k.shape[-2]
 
     @property
     def n_inject(self) -> int:
-        return self.inj_k.shape[1]
+        return self.inj_k.shape[-2]
 
 
 @_register
@@ -137,17 +141,17 @@ def init_synapse_cache(
     zi = lambda *s: jnp.zeros(s, jnp.int32)
     zf = lambda *s: jnp.zeros(s, jnp.float32)
     return SynapseCache(
-        lm_k=z(batch, n_landmarks, hkv, d),
-        lm_v=z(batch, n_landmarks, hkv, d),
+        lm_k=z(batch, n_landmarks, hkv * d),
+        lm_v=z(batch, n_landmarks, hkv * d),
         lm_pos=zi(batch, n_landmarks),
         lm_score=jnp.full((batch, n_landmarks), -jnp.inf, jnp.float32),
         lm_count=zi(batch),
-        win_k=z(batch, window, hkv, d),
-        win_v=z(batch, window, hkv, d),
+        win_k=z(batch, window, hkv * d),
+        win_v=z(batch, window, hkv * d),
         win_pos=zi(batch, window),
         win_score=zf(batch, window),
-        inj_k=z(batch, max(n_inject, 1), hkv, d),
-        inj_v=z(batch, max(n_inject, 1), hkv, d),
+        inj_k=z(batch, max(n_inject, 1), hkv * d),
+        inj_v=z(batch, max(n_inject, 1), hkv * d),
         inj_pos=zi(batch, max(n_inject, 1)),
         inj_count=zi(batch),
         win_count=zi(batch),

@@ -532,26 +532,42 @@ def decode_step(params, cfg: ModelConfig, inputs: dict, caches: ModelCaches, *, 
     positions = inputs["positions"]
 
     groups = cfg.layer_groups()
+    # a synapse group's caches ride the layer scan's carry, each layer
+    # reading and writing its own slice of the stack in place (the attend
+    # kernel reads the stacked K/V at the layer's index); other caches
+    # pass through the scan's inputs and outputs, one layer's slice each
+    stacked = spec.kind == "synapse" and cfg.attn_kind != "mla"
+
+    def attn_block(p_layer, x_c, attend, grp):
+        h = rms_norm(x_c, p_layer["ln1"], cfg.norm_eps)
+        y, new_cache = attend(h)
+        x_c = _radd(x_c, y)
+        h = rms_norm(x_c, p_layer["ln2"], cfg.norm_eps)
+        if grp.mlp == "moe":
+            y, _ = moe.moe_forward(p_layer["mlp"], cfg, h)
+        else:
+            y = swiglu(p_layer["mlp"], h)
+        return _radd(x_c, y), new_cache
+
+    def synapse_body(grp):
+        def body(carry, xs):
+            x_c, stack = carry
+            p_layer, layer = xs
+            attend = lambda h: synapse_lib.synapse_decode(
+                p_layer["attn"], cfg, h, stack, positions, spec.policy, layer=layer)[:2]
+            return attn_block(p_layer, x_c, attend, grp), None
+        return body
 
     def block_body(grp):
         def body(carry, xs):
             p_layer, cache = xs
             x_c = carry
             if grp.kind == "attn":
-                h = rms_norm(x_c, p_layer["ln1"], cfg.norm_eps)
                 if cfg.attn_kind == "mla":
-                    y, new_cache, _ = mla.mla_decode(p_layer["attn"], cfg, h, cache, positions)
-                elif spec.kind == "synapse":
-                    y, new_cache, _ = synapse_lib.synapse_decode(p_layer["attn"], cfg, h, cache, positions, spec.policy)
+                    attend = lambda h: mla.mla_decode(p_layer["attn"], cfg, h, cache, positions)[:2]
                 else:
-                    y, new_cache, _ = attention.attention_decode_full(p_layer["attn"], cfg, h, cache, positions)
-                x_c = _radd(x_c, y)
-                h = rms_norm(x_c, p_layer["ln2"], cfg.norm_eps)
-                if grp.mlp == "moe":
-                    y, _ = moe.moe_forward(p_layer["mlp"], cfg, h)
-                else:
-                    y = swiglu(p_layer["mlp"], h)
-                return _radd(x_c, y), new_cache
+                    attend = lambda h: attention.attention_decode_full(p_layer["attn"], cfg, h, cache, positions)[:2]
+                return attn_block(p_layer, x_c, attend, grp)
             if grp.kind == "mamba2":
                 h = rms_norm(x_c, p_layer["ln"], cfg.norm_eps)
                 y, new_cache = mamba2.mamba2_decode(p_layer["mixer"], cfg, h, cache)
@@ -571,24 +587,32 @@ def decode_step(params, cfg: ModelConfig, inputs: dict, caches: ModelCaches, *, 
     for seg in build_segments(cfg):
         grp = groups[seg.group]
         p_seg = _slice_group(params["groups"][seg.group], seg.start, seg.count)
-        c_seg = _slice_group(seg_caches[seg.group], seg.start, seg.count)
-        x_cur, new_c = _scan_stack(block_body(grp), x_cur, (p_seg, c_seg), seg.count, cfg.scan_layers)
-        seg_caches[seg.group] = jax.tree.map(
-            lambda full, part: jax.lax.dynamic_update_slice_in_dim(full, part, seg.start, axis=0),
-            seg_caches[seg.group],
-            new_c,
-        )
+        if grp.kind == "attn" and stacked:
+            layers = seg.start + jnp.arange(seg.count, dtype=jnp.int32)
+            (x_cur, seg_caches[seg.group]), _ = _scan_stack(
+                synapse_body(grp), (x_cur, seg_caches[seg.group]), (p_seg, layers),
+                seg.count, cfg.scan_layers)
+        else:
+            c_seg = _slice_group(seg_caches[seg.group], seg.start, seg.count)
+            x_cur, new_c = _scan_stack(block_body(grp), x_cur, (p_seg, c_seg), seg.count, cfg.scan_layers)
+            seg_caches[seg.group] = jax.tree.map(
+                lambda full, part: jax.lax.dynamic_update_slice_in_dim(full, part, seg.start, axis=0),
+                seg_caches[seg.group],
+                new_c,
+            )
         if seg.shared_after >= 0:
-            inv_cache = jax.tree.map(lambda a: a[seg.shared_after], shared_cache)
             h = rms_norm(x_cur, params["shared_attn"]["ln1"], cfg.norm_eps)
             if spec.kind == "synapse":
-                y, new_inv, _ = synapse_lib.synapse_decode(params["shared_attn"]["attn"], cfg, h, inv_cache, positions, spec.policy)
+                y, shared_cache, _ = synapse_lib.synapse_decode(
+                    params["shared_attn"]["attn"], cfg, h, shared_cache, positions, spec.policy,
+                    layer=seg.shared_after)
             else:
+                inv_cache = jax.tree.map(lambda a: a[seg.shared_after], shared_cache)
                 y, new_inv, _ = attention.attention_decode_full(params["shared_attn"]["attn"], cfg, h, inv_cache, positions)
+                shared_cache = jax.tree.map(lambda full, part: full.at[seg.shared_after].set(part), shared_cache, new_inv)
             x_cur = _radd(x_cur, y)
             h = rms_norm(x_cur, params["shared_attn"]["ln2"], cfg.norm_eps)
             x_cur = _radd(x_cur, swiglu(params["shared_attn"]["mlp"], h))
-            shared_cache = jax.tree.map(lambda full, part: full.at[seg.shared_after].set(part), shared_cache, new_inv)
 
     hidden = rms_norm(x_cur[:, 0, :], params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["head"]

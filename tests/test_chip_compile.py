@@ -76,6 +76,29 @@ def test_synapse_attention_compiles(one_chip, B, T):
     )
 
 
+@pytest.mark.parametrize("model,B", [("qwen2.5-0.5b", 256), ("qwen3-4b", 64)])
+def test_synapse_attend_reads_pieces_in_place(one_chip, monkeypatch, model, B):
+    """The side pass's attend at the council's shapes (64 landmarks, 64
+    window and 16 inject slots, in the side cache's stacks of every layer;
+    256 lanes at qwen2.5-0.5b, 64 at qwen3-4b, whose 8 kv heads of 128
+    make the widest row) at a traced layer: one kernel call over the three
+    pieces, within the chip's VMEM, with no pad, no concatenate and no
+    slice of a layer in front of it."""
+    monkeypatch.setattr(ops, "INTERPRET", False)
+    cfg = get_config(model)
+    sizes, width = (64, 64, 16), cfg.n_kv_heads * cfg.d_head
+    s = lambda shape, dt=BF16: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    stacks = [(s((cfg.n_layers, B, T, width)), s((cfg.n_layers, B, T, width))) for T in sizes]
+    valids = [s((B, T), jnp.bool_) for T in sizes]
+    text = _compile(lambda q, p, m, layer: ops.synapse_attend(q, p, m, layer=layer),
+                    s((B, cfg.n_heads, cfg.d_head)), stacks, valids, s((), jnp.int32)).as_text()
+    calls = re.findall(r'%(\w+?)(?:\.\d+)? = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert calls == ["synapse_attention"]
+    ops_used = set(re.findall(r"= [^\n=]*? ([a-z][\w-]*)\(", text))
+    assert not {"pad", "concatenate", "dynamic-slice"} & ops_used, sorted(ops_used)
+    assert not re.search(rf"= bf16\[{B},(64|16),{width}\]", text)  # no copy of one layer's piece
+
+
 @pytest.mark.parametrize("n_landmarks", [0, 8], ids=["density_only", "landmarks"])
 def test_landmark_score_compiles(one_chip, n_landmarks):
     B, T = 4, 512

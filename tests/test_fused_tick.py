@@ -192,11 +192,12 @@ def test_synapse_decode_pallas_matches_piece():
     )
     x = jax.random.normal(ks[5], (B, 1, cfg.d_model))
     positions = jnp.asarray([3, 40, 90], jnp.int32)
+    stack = jax.tree.map(lambda a: a[None], cache)  # the decode scan's stack of one layer
     outs = {}
     for impl in ("pallas", "piece"):
         policy = synapse_lib.SynapsePolicy(attend_impl=impl)
         y, new_cache, stats = synapse_lib.synapse_decode(
-            params, cfg, x, cache, positions, policy
+            params, cfg, x, stack, positions, policy, layer=0
         )
         outs[impl] = (y, new_cache, stats)
     y_p, c_p, st_p = outs["pallas"]

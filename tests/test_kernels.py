@@ -106,6 +106,84 @@ def test_synapse_attention_grid_variant_matches_ref(dtype):
     assert float(jnp.where(valid, 0.0, mass).max()) == 0.0
 
 
+# (H, Hkv, D): qwen2.5-0.5b's and qwen3-4b's widths; pieces are the side
+# cache's landmarks / window / inject slots
+PIECE_SHAPES = {"qwen2.5-0.5b": (14, 2, 64), "qwen3-4b": (32, 8, 128)}
+PIECE_SIZES = (64, 64, 16)
+
+
+def _piece_lanes(model, dtype) -> int:
+    """24 lanes take three grid steps of 8. Eight lanes of qwen3-4b's f32
+    rows overflow a step's VMEM, so that case takes 4 lanes, one block."""
+    return 4 if (model, dtype) == ("qwen3-4b", jnp.float32) else 24
+
+
+def _piece_masks(key, B, sizes):
+    """Prefix fills per lane, as the decode's counters give them: lane 0
+    full, lane 1 a single valid slot (its window's first), lane 2 an empty
+    inject piece, the rest random."""
+    counts = []
+    for i, T in enumerate(sizes):
+        c = jax.random.randint(jax.random.fold_in(key, i), (B,), 0, T + 1)
+        c = c.at[0].set(T).at[1].set(1 if i == 1 else 0)
+        if i == 2:
+            c = c.at[2].set(0)
+        counts.append(c)
+    return [jnp.arange(T)[None, :] < c[:, None] for T, c in zip(sizes, counts)]
+
+
+@pytest.mark.parametrize("model", list(PIECE_SHAPES))
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_synapse_attention_pieces_matches_ref(model, dtype):
+    """The piece-wise kernel's grid (the lane blocks it picks, every kv
+    head in a row, one softmax over the three pieces, K/V read at one layer
+    of their stacks) under the interpreter, against the oracle on the
+    joined key set of that layer."""
+    from repro.kernels import synapse_attention as sa
+
+    H, Hkv, D = PIECE_SHAPES[model]
+    B, NL, layer = _piece_lanes(model, dtype), 3, 1
+    ks = jax.random.split(jax.random.key(6), 8)
+    q = jax.random.normal(ks[0], (B, H, D)).astype(dtype)
+    stacks = [tuple(jax.random.normal(ks[1 + 2 * i + j], (NL, B, T, Hkv * D)).astype(dtype)
+                    for j in range(2)) for i, T in enumerate(PIECE_SIZES)]
+    valids = _piece_masks(ks[7], B, PIECE_SIZES)
+    assert sa.fits_in_place(B, H, PIECE_SIZES, Hkv * D, jnp.dtype(dtype).itemsize)
+    out, masses = sa.synapse_attention_pieces(
+        q, stacks, valids, jnp.int32(layer), interpret=True)
+
+    pieces = [(k[layer], v[layer]) for k, v in stacks]
+    heads = lambda a: a.reshape(B, -1, Hkv, D)
+    out_r, mass_r = ref.synapse_attention_ref(
+        q, jnp.concatenate([heads(k) for k, _ in pieces], 1),
+        jnp.concatenate([heads(v) for _, v in pieces], 1), jnp.concatenate(valids, 1))
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(out_r, np.float32), **_tol(dtype)
+    )
+    splits = np.cumsum(PIECE_SIZES)[:-1]
+    for mass, m_r, valid in zip(masses, np.split(np.asarray(mass_r), splits, 1), valids):
+        assert mass.dtype == jnp.float32 and mass.shape == valid.shape
+        np.testing.assert_allclose(np.asarray(mass), m_r, **_tol(dtype))
+        assert float(jnp.where(valid, 0.0, mass).max()) == 0.0
+    total = sum(np.asarray(m, np.float64).sum(-1) for m in masses)
+    np.testing.assert_allclose(total, H, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(masses[1][1, 0]), H, rtol=1e-5)
+
+
+@pytest.mark.parametrize("B,H,sizes,width,itemsize,fits", [
+    (256, 14, (64, 64, 16), 2 * 64, 2, True),    # qwen2.5-0.5b council lanes
+    (64, 32, (64, 64, 16), 8 * 128, 2, True),    # qwen3-4b council lanes
+    (64, 32, (64, 64, 16), 8 * 128, 4, False),   # qwen3-4b in f32: 8 lanes overflow VMEM
+    (256, 14, (64, 64, 1), 2 * 64, 2, False),    # n_inject 0 keeps one slot: J = 1
+    (256, 14, (64, 64, 16), 3 * 64, 2, False),   # a row of 192 lanes
+    (4, 14, (64, 64, 16), 2 * 64, 2, True),      # fewer lanes than a block: one step
+], ids=["qwen2.5-0.5b", "qwen3-4b", "qwen3-4b_f32", "no_inject", "width_192", "four_lanes"])
+def test_synapse_pieces_predicate(B, H, sizes, width, itemsize, fits):
+    from repro.kernels import synapse_attention as sa
+
+    assert sa.fits_in_place(B, H, sizes, width, itemsize) is fits
+
+
 def test_landmark_score_multi_block_matches_ref():
     """Several key blocks per row: each block writes its own lane slice of
     the logits and distance rows."""

@@ -29,6 +29,11 @@ def test_onehot_write_mask():
     assert float(out[0, 1]) == 5.0 and float(out[1, 2]) == 0.0
 
 
+def _stack(a):
+    """[B,T,Hkv,D] -> the synapse cache's stack of one layer, [1,B,T,Hkv*D]."""
+    return a.reshape((1,) + a.shape[:2] + (-1,))
+
+
 def test_piece_attend_matches_decode_attend():
     B, H, Hkv, D = 2, 8, 4, 32
     ks = jax.random.split(jax.random.key(0), 7)
@@ -41,7 +46,8 @@ def test_piece_attend_matches_decode_attend():
         pieces.append((k, v))
         valids.append(jax.random.bernoulli(ks[i], 0.8, (B, T)).at[:, 0].set(True))
     scale = 1.0 / (D ** 0.5)
-    out, masses = sh.piece_attend(q, pieces, valids, scale)
+    stacks = [(_stack(k), _stack(v)) for k, v in pieces]
+    out, masses = sh.piece_attend(q, stacks, valids, scale, layer=0)
 
     keys = jnp.concatenate([k for k, _ in pieces], axis=1)
     vals = jnp.concatenate([v for _, v in pieces], axis=1)
@@ -107,10 +113,10 @@ def test_onehot_sharded_formulation_matches_scatter():
 
 def test_piece_attend_requires_mesh_with_axis():
     q = jnp.zeros((1, 4, 8))
-    k = jnp.zeros((1, 4, 2, 8))
+    k = _stack(jnp.zeros((1, 4, 2, 8)))
     valid = jnp.ones((1, 4), bool)
     with pytest.raises(ValueError, match="no mesh"):
-        sh.piece_attend(q, [(k, k)], [valid], 0.5,
+        sh.piece_attend(q, [(k, k)], [valid], 0.5, layer=0,
                         ctx=sh.ShardContext(axis="model"))
 
 
@@ -126,12 +132,12 @@ def test_piece_attend_sharded_matches_local():
     for i, T in enumerate((8, 4)):
         k = jax.random.normal(ks[1 + i], (B, T, Hkv, D))
         v = jax.random.normal(ks[3 + i], (B, T, Hkv, D))
-        pieces.append((k, v))
+        pieces.append((_stack(k), _stack(v)))
         valids.append(jnp.ones((B, T), bool).at[:, -1].set(i == 0))
     scale = 1.0 / (D ** 0.5)
-    out_l, mass_l = sh.piece_attend(q, pieces, valids, scale)
+    out_l, mass_l = sh.piece_attend(q, pieces, valids, scale, layer=0)
     out_s, mass_s = sh.piece_attend(
-        q, pieces, valids, scale, ctx=sh.ShardContext("model", mesh)
+        q, pieces, valids, scale, layer=0, ctx=sh.ShardContext("model", mesh)
     )
     np.testing.assert_allclose(np.asarray(out_l), np.asarray(out_s), rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(
