@@ -1,8 +1,10 @@
 """Chip smoke test: the cortex engine and its serving path on one TPU chip,
-at qwen2.5-0.5b's published widths (24 layers, d_model 896, 14/2 heads,
-d_head 64, d_ff 4864, vocab 151936, bf16) with random weights from a seed.
+at a model's published widths (qwen2.5-0.5b by default: 24 layers, d_model
+896, 14/2 heads, d_head 64, d_ff 4864, vocab 151936) with random bf16
+weights from a seed, held once.
 
     python chip_smoke.py            # one chip: kernels, council, hibernate/wake, serving
+    python chip_smoke.py --arch qwen3-4b  # the same at Qwen3-4B's widths
     python chip_smoke.py --lanes 4  # four chips: lane-mesh council vs the one-chip council
 
 Everything runs in this one process (a chip belongs to the process that
@@ -24,6 +26,7 @@ anything but a TPU exits non-zero before any phase runs.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
@@ -96,7 +99,7 @@ def check_kernels(cfg, seed: int) -> None:
     """Both kernels against the f32 oracle in ``kernels/ref.py``, at the
     model's head widths: the side set (landmarks + window + inject slots)
     and the river's cache, then the side pass's in-place attend over the
-    three pieces at the council's 256 lanes and at qwen3-4b's widths."""
+    three pieces at the council's 256 lanes and at qwen3-4b's 64."""
     import jax
     import jax.numpy as jnp
 
@@ -145,12 +148,14 @@ def check_kernels(cfg, seed: int) -> None:
     # the side pass's attend, its three pieces read in place from stacks of
     # every layer's lane-dense rows, partly filled: the council's 256 lanes
     # at this model's widths, and 64 lanes at qwen3-4b's (8 kv heads of 128,
-    # the widest row, 8 lanes a grid step)
+    # the widest row, 8 lanes a grid step), its council's lanes also where
+    # qwen3-4b is this model
     from repro.configs import get_config
     from repro.kernels import synapse_attention as sa
 
     sizes = (64, 64, 16)
-    for c, B in ((cfg, 256), (get_config("qwen3-4b"), 64)):
+    widths = {cfg.name: (cfg, 256), "qwen3-4b": (get_config("qwen3-4b"), 64)}
+    for c, B in widths.values():
         H, Hkv, D, NL = c.n_heads, c.n_kv_heads, c.d_head, c.n_layers
         layer = NL - 1
         assert sa.fits_in_place(B, H, sizes, Hkv * D, 2), (c.name, "must take the in-place path")
@@ -323,6 +328,7 @@ def main(argv=None) -> None:
                     help="run only the lane-mesh council on N chips against "
                          "the one-chip council")
     ap.add_argument("--seed", type=int, default=0, help="weights and kernel inputs")
+    ap.add_argument("--arch", default="qwen2.5-0.5b", help="the model (repro.configs)")
     args = ap.parse_args(argv)
 
     dev = require_tpu()
@@ -342,12 +348,13 @@ def main(argv=None) -> None:
             cache["writes"] += 1
 
     jax.monitoring.register_event_listener(on_event)
-    cfg = get_config("qwen2.5-0.5b")
+    cfg = dataclasses.replace(get_config(args.arch), param_dtype="bfloat16")
     log(f"jax {jax.__version__}; device {dev.device_kind} ({dev.platform}), "
         f"{jax.device_count()} visible")
     log(f"config {cfg.name}: {cfg.n_layers} layers, d_model {cfg.d_model}, "
         f"{cfg.n_heads}/{cfg.n_kv_heads} heads, d_head {cfg.d_head}, d_ff {cfg.d_ff}, "
-        f"vocab {cfg.vocab_size}, {cfg.compute_dtype}; random weights, seed {args.seed}")
+        f"vocab {cfg.vocab_size}, {cfg.compute_dtype}; random {cfg.param_dtype} weights, "
+        f"seed {args.seed}")
     t0 = time.perf_counter()
     params = model_lib.init_params(jax.random.key(args.seed), cfg)
     jax.block_until_ready(params)

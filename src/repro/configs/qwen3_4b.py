@@ -1,4 +1,4 @@
-"""Qwen3-4B [hf:Qwen/Qwen3-8B family] — dense GQA, qk_norm, head_dim 128."""
+"""Qwen3-4B [hf:Qwen/Qwen3-4B] — dense GQA, qk_norm, head_dim 128, tied head."""
 from repro.models.config import ModelConfig
 
 CONFIG = ModelConfig(
@@ -12,5 +12,6 @@ CONFIG = ModelConfig(
     d_ff=9728,
     vocab_size=151936,
     qk_norm=True,
+    tie_embeddings=True,
     rope_theta=1_000_000.0,
 )

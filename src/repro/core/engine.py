@@ -704,6 +704,8 @@ class CortexEngine:
             "overlapped_drains": 0, "window_hist": {},
             # sides started, and [TASK] triggers refused for want of a lane
             "spawns": 0, "spawns_dropped": 0,
+            # sides merged back, and merges whose thought the gate let in
+            "merges": 0, "merges_accepted": 0,
             # tiered-memory telemetry
             "hibernates": 0, "wakes": 0,
             # resilience telemetry (ISSUE 8): wake_failures = transient
@@ -1812,6 +1814,8 @@ class CortexEngine:
             self.stats["aux_dispatches"] += 2
             accepted = bool(np.asarray(accept)[s.parent_lane])  # drain-time sync
             self.stats["host_syncs"] += 1
+            self.stats["merges"] += 1
+            self.stats["merges_accepted"] += int(accepted)
             self.history.append(
                 {
                     "event": "merge",

@@ -1,5 +1,5 @@
 """Compile the main path for a described TPU v5e chip, at qwen2.5-0.5b's
-published widths, with no chip attached.
+and qwen3-4b's published widths, with no chip attached.
 
 The TPU compiler refuses what interpret mode accepts: blocks that break the
 (8, 128) tiling and kernels that need more VMEM than a core has. Compiling
@@ -10,6 +10,7 @@ The topology is described inside a fixture (never at import: only one
 process may load the TPU library, and every test worker imports this file),
 and every compile stays in this one file.
 """
+import dataclasses
 import functools
 import os
 import re
@@ -22,6 +23,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
 from repro.core import engine as engine_lib
+from repro.core import injection
 from repro.kernels import ops
 from repro.launch import sharding as shard_lib
 from repro.launch.mesh import make_lane_mesh
@@ -180,3 +182,36 @@ def test_spawn_program_compiles_under_its_name(one_chip, monkeypatch):
     text = compiled.as_text()
     assert _module_name(text) == "jit_engine_spawn"
     assert _kernel_calls(text) == {"landmark_score"}
+
+
+def test_qwen3_4b_council_fits_one_chip(one_chip, monkeypatch):
+    """qwen3-4b's council (2 rivers of 2304 slots, 64 side lanes, bf16
+    weights held once): the 8-tick window, with the side attend in one
+    kernel call, and the merge, which runs a thought through all 36 layers,
+    each fit one 16 GB chip with the weights and caches it holds."""
+    monkeypatch.setattr(ops, "INTERPRET", False)
+    cfg = dataclasses.replace(get_config("qwen3-4b"), param_dtype="bfloat16")
+    main_spec = model_lib.CacheSpec(kind="full", capacity=2304)
+    greedy = SamplingParams(greedy=True)
+    place = lambda tree: _placed(tree, jax.tree.map(lambda _: one_chip, tree))
+    state = place(jax.eval_shape(lambda: engine_lib.init_tick_state(
+        cfg, n_main=2, max_side=64, main_spec=main_spec, side_spec=SIDE_SPEC,
+        ring_capacity=8, side_prompt_cap=64, main_sampling=greedy, side_sampling=greedy,
+    )))
+    params = place(model_lib.abstract_params(cfg))
+    window = functools.partial(
+        engine_lib.fused_tick, cfg=cfg, main_spec=main_spec, side_spec=SIDE_SPEC,
+        step_sides=True, use_filters=False, any_greedy=True, n_ticks=8,
+    )
+    compiled = _compile(window, params, state)
+    assert _kernel_calls(compiled.as_text()) == {"synapse_attention"}
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    merge = jax.jit(
+        lambda p, mc, mh, toks, vpos, mask: injection.merge_thought(
+            p, cfg, mc, mh, toks, vpos, mask, -1.0),
+        donate_argnums=(1,),
+    ).lower(params, state.main_caches, state.main_hidden, s((2, 16), jnp.int32),
+            s((2,), jnp.int32), s((2,), jnp.bool_)).compile()
+    for c in (compiled, merge):
+        mem = c.memory_analysis()
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9

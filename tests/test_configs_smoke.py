@@ -84,6 +84,7 @@ def test_param_counts_plausible():
     approx = {
         "smollm-135m": (0.134e9, 0.35),
         "qwen3-8b": (8.2e9, 0.35),
+        "qwen3-4b": (4.02e9, 0.1),
         "qwen1.5-110b": (111e9, 0.25),
         "deepseek-v2-236b": (236e9, 0.35),
         "qwen3-moe-30b-a3b": (30.5e9, 0.35),

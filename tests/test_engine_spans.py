@@ -1,10 +1,11 @@
-"""The control plane's host spans and spawn counters, read back from a
-profiler trace of a short council served through ``ServingFrontend``.
+"""The control plane's host spans and spawn and merge counters, read back
+from a profiler trace of a short council served through ``ServingFrontend``.
 
 The spans are ``jax.profiler.TraceAnnotation``s, so they land in the
 profiler's host plane on the clock the device events are placed on. One
 river's prompt carries more ``[TASK]`` tags than there are side lanes, so
-one spawn is refused and counted.
+one spawn is refused and counted; every merge is counted, and with the
+gate open every one is counted as accepted.
 """
 import dataclasses
 import glob
@@ -115,6 +116,16 @@ def test_spawn_counters_match_the_history(served):
     # three tags, two side lanes: the third trigger is refused and counted
     assert eng.stats["spawns_dropped"] == 1
     assert sum(1 for s in spans if s["name"] == "engine.spawn") == spawns + 1
+
+
+def test_merge_counters_match_the_history(served):
+    eng, _, spans, _ = served
+    merges = [ev for ev in eng.history if ev["event"] == "merge"]
+    assert merges and eng.stats["merges"] == len(merges)
+    # the gate's threshold is -1: every thought is let in
+    assert all(ev["accepted"] for ev in merges)
+    assert eng.stats["merges_accepted"] == eng.stats["merges"]
+    assert sum(1 for s in spans if s["name"] == "engine.merge") == len(merges)
 
 
 def test_engine_programs_carry_their_names(served):
