@@ -51,7 +51,8 @@ scheduler hot loop*:
 * Spawn = hybrid landmark compression of the *parent lane only* (paper
   §3.3), via the fused ``kernels.ops.landmark_score`` sweep; merge =
   Validation Gate (§3.5) + Referential Injection (§3.6) fused into one
-  dispatch (``injection.merge_thought``).
+  dispatch per drain (``injection.merge_thoughts``: every side that
+  finished in the drain, in chunks of at most ``MERGE_BATCH``).
 
 Performance invariants (asserted by tests/test_fused_tick.py,
 tests/test_macro_tick.py, and tests/test_adaptive_pipeline.py):
@@ -105,6 +106,10 @@ from repro.serving.sampler import (
 # host plane, on the clock the device events are placed on; with none, a
 # span costs about a microsecond. Keyword ids are ones the code already holds.
 _span = jax.profiler.TraceAnnotation
+
+# most thoughts one merge program takes: a drain's merges run in chunks of
+# this many, each padded to a power of two ({1, 2, 4, ..., MERGE_BATCH})
+MERGE_BATCH = 32
 
 
 def _lane_slice(tree, lane: int):
@@ -704,8 +709,9 @@ class CortexEngine:
             "overlapped_drains": 0, "window_hist": {},
             # sides started, and [TASK] triggers refused for want of a lane
             "spawns": 0, "spawns_dropped": 0,
-            # sides merged back, and merges whose thought the gate let in
-            "merges": 0, "merges_accepted": 0,
+            # sides merged back, merges whose thought the gate let in, and
+            # the batched merge programs that ran them
+            "merges": 0, "merges_accepted": 0, "merge_dispatches": 0,
             # tiered-memory telemetry
             "hibernates": 0, "wakes": 0,
             # resilience telemetry (ISSUE 8): wake_failures = transient
@@ -715,6 +721,7 @@ class CortexEngine:
             "wake_failures": 0, "lost_agents": 0, "recoveries": 0,
         }
         self._pending = 0  # ticks since last drain (== device ring cursor)
+        self._merge_widths_ready = False  # every merge program width compiled
 
         cfg = self.cfg
         # Serving-dtype weights, cast ONCE (the per-dispatch cast_params
@@ -776,8 +783,8 @@ class CortexEngine:
         def engine_prefill(p, toks, c, lane):
             return model_lib.prefill_lane(p, jcfg, {"tokens": toks}, c, lane, spec=self.main_spec)
 
-        def engine_merge(p, mc, mh, toks, vpos, mask):
-            return injection.merge_thought(p, jcfg, mc, mh, toks, vpos, mask, self.theta)
+        def engine_merge(p, mc, mh, toks, parent, vpos, valid):
+            return injection.merge_thoughts(p, jcfg, mc, mh, toks, parent, vpos, valid, self.theta)
 
         self._jit_prefill_lane = _jit(
             engine_prefill, (2,), (rep, rep, ssh.main_caches) if ssh else None,
@@ -1336,8 +1343,8 @@ class CortexEngine:
 
             # 3. merges (free lanes before new spawns claim them)
             assert not (overlapped and finished), "pipeline gate violated: merge"
-            for s, thought in finished:
-                self._merge_side(s, thought)
+            if finished:
+                self._merge_sides(finished)
             quiet = quiet and not finished
 
             # 4. river triggers spawn new streams
@@ -1795,41 +1802,75 @@ class CortexEngine:
             return did
 
     # ------------------------------------------------------------------
-    def _merge_side(self, s: AgentView, thought: str):
-        with _span("engine.merge", agent=s.agent_id,
-                   parent=self.mains[s.parent_lane].agent_id):
-            ids = self.tok.encode(thought)[-self.inject_tokens:]
-            ids = ids + [self.tok.pad_id] * (self.inject_tokens - len(ids))
-            toks = jnp.tile(jnp.asarray(ids, jnp.int32)[None], (self.n_main, 1))
-            vpos = jnp.asarray([m.position for m in self.mains], jnp.int32)  # virtual index
-            lane_mask = jnp.arange(self.n_main) == s.parent_lane
-            new_caches, accept, score = self._jit_merge(
-                self._params, self.state.main_caches, self.state.main_hidden,
-                toks, vpos, lane_mask,
-            )
-            act_a = self._jit_retire_side(self.state.side_active, s.lane)
-            self.state = dataclasses.replace(
-                self.state, main_caches=new_caches, side_active=act_a
-            )
-            self.stats["aux_dispatches"] += 2
-            accepted = bool(np.asarray(accept)[s.parent_lane])  # drain-time sync
+    def _merge_sides(self, finished: list):
+        """Merge a drain's finished sides: one batched merge program and one
+        retire of their lanes per chunk of at most ``MERGE_BATCH`` thoughts,
+        then ONE host read of every gate. History entries, releases and
+        router resets follow in ``finished`` order."""
+        parents = dict.fromkeys(self.mains[s.parent_lane].agent_id for s, _ in finished)
+        with _span("engine.merge", n=len(finished), parent=",".join(parents)):
+            if not self._merge_widths_ready:
+                # how many sides finish in one drain varies with their task
+                # lengths: compile every width a drain can need now, with
+                # programs that merge nothing, so none compiles mid-stream
+                top = 1 << (min(self.max_side, MERGE_BATCH) - 1).bit_length()
+                for i in range(top.bit_length()):
+                    self._merge_program([], 1 << i, finished[0][0].lane)
+                self._merge_widths_ready = True
+            results = []
+            for c in range(0, len(finished), MERGE_BATCH):
+                chunk = finished[c:c + MERGE_BATCH]
+                width = 1 << (len(chunk) - 1).bit_length()
+                results.append(self._merge_program(chunk, width, chunk[0][0].lane))
+                self.stats["merge_dispatches"] += 1
+            results = jax.device_get(results)  # drain-time sync: every gate at once
             self.stats["host_syncs"] += 1
-            self.stats["merges"] += 1
-            self.stats["merges_accepted"] += int(accepted)
-            self.history.append(
-                {
-                    "event": "merge",
-                    "agent": s.agent_id,
-                    "accepted": accepted,
-                    "gate_score": float(np.asarray(score)[s.parent_lane]),
-                    "thought": thought[:80],
-                }
-            )
-            self.router.reset(s.agent_id)
-            self.prism.release(s.agent_id)
-            self.registry.release(s.agent_id)
-            self._decoders.pop(s.agent_id, None)
-            s.active = False
+            # chunks are full but the last, whose padding rows come at its end
+            accepts = np.concatenate([a for a, _ in results])
+            scores = np.concatenate([sc for _, sc in results])
+            for k, (s, thought) in enumerate(finished):
+                accepted = bool(accepts[k])
+                self.stats["merges"] += 1
+                self.stats["merges_accepted"] += int(accepted)
+                self.history.append(
+                    {
+                        "event": "merge",
+                        "agent": s.agent_id,
+                        "accepted": accepted,
+                        "gate_score": float(scores[k]),
+                        "thought": thought[:80],
+                    }
+                )
+                self.router.reset(s.agent_id)
+                self.prism.release(s.agent_id)
+                self.registry.release(s.agent_id)
+                self._decoders.pop(s.agent_id, None)
+                s.active = False
+
+    def _merge_program(self, rows: list, width: int, pad_lane: int):
+        """Dispatch the merge program over ``rows`` ([(side, thought)]),
+        padded to ``width`` rows that merge nothing, and the retire of the
+        rows' side lanes, whose padding repeats ``pad_lane`` (a lane being
+        retired anyway). Returns the program's device (accept, score)."""
+        T = self.inject_tokens
+        toks = np.full((width, T), self.tok.pad_id, np.int32)
+        parent = np.zeros(width, np.int32)
+        vpos = np.zeros(width, np.int32)  # virtual index: the parent's position
+        valid = np.zeros(width, bool)
+        lanes = np.full(width, pad_lane, np.int32)
+        for i, (s, thought) in enumerate(rows):
+            ids = self.tok.encode(thought)[-T:]
+            toks[i, :len(ids)] = ids
+            parent[i], vpos[i] = s.parent_lane, self.mains[s.parent_lane].position
+            valid[i], lanes[i] = True, s.lane
+        new_caches, accept, score = self._jit_merge(
+            self._params, self.state.main_caches, self.state.main_hidden,
+            toks, parent, vpos, valid,
+        )
+        act_a = self._jit_retire_side(self.state.side_active, lanes)
+        self.state = dataclasses.replace(self.state, main_caches=new_caches, side_active=act_a)
+        self.stats["aux_dispatches"] += 2
+        return accept, score
 
     # ------------------------------------------------------------------
     def memory_report(self) -> dict:

@@ -187,8 +187,10 @@ def test_spawn_program_compiles_under_its_name(one_chip, monkeypatch):
 def test_qwen3_4b_council_fits_one_chip(one_chip, monkeypatch):
     """qwen3-4b's council (2 rivers of 2304 slots, 64 side lanes, bf16
     weights held once): the 8-tick window, with the side attend in one
-    kernel call, and the merge, which runs a thought through all 36 layers,
-    each fit one 16 GB chip with the weights and caches it holds."""
+    kernel call, and a drain's batched merge of 32 thoughts, which runs
+    them through all 36 layers, each fit one 16 GB chip with the weights
+    and caches it holds. The merge writes the thoughts into the donated
+    river caches in place: its temporaries hold no copy of them."""
     monkeypatch.setattr(ops, "INTERPRET", False)
     cfg = dataclasses.replace(get_config("qwen3-4b"), param_dtype="bfloat16")
     main_spec = model_lib.CacheSpec(kind="full", capacity=2304)
@@ -206,12 +208,15 @@ def test_qwen3_4b_council_fits_one_chip(one_chip, monkeypatch):
     compiled = _compile(window, params, state)
     assert _kernel_calls(compiled.as_text()) == {"synapse_attention"}
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    K = engine_lib.MERGE_BATCH
     merge = jax.jit(
-        lambda p, mc, mh, toks, vpos, mask: injection.merge_thought(
-            p, cfg, mc, mh, toks, vpos, mask, -1.0),
+        lambda p, mc, mh, toks, parent, vpos, valid: injection.merge_thoughts(
+            p, cfg, mc, mh, toks, parent, vpos, valid, -1.0),
         donate_argnums=(1,),
-    ).lower(params, state.main_caches, state.main_hidden, s((2, 16), jnp.int32),
-            s((2,), jnp.int32), s((2,), jnp.bool_)).compile()
+    ).lower(params, state.main_caches, state.main_hidden, s((K, 16), jnp.int32),
+            s((K,), jnp.int32), s((K,), jnp.int32), s((K,), jnp.bool_)).compile()
     for c in (compiled, merge):
         mem = c.memory_analysis()
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
+    river_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state.main_caches))
+    assert merge.memory_analysis().temp_size_in_bytes < river_bytes

@@ -5,7 +5,9 @@ The spans are ``jax.profiler.TraceAnnotation``s, so they land in the
 profiler's host plane on the clock the device events are placed on. One
 river's prompt carries more ``[TASK]`` tags than there are side lanes, so
 one spawn is refused and counted; every merge is counted, and with the
-gate open every one is counted as accepted.
+gate open every one is counted as accepted. The sides that finish in one
+drain merge in one batch: one ``engine.merge`` span carries how many
+(``n``) and their river (``parent``), and the history names the agents.
 """
 import dataclasses
 import glob
@@ -25,7 +27,8 @@ from repro.serving.sampler import SamplingParams
 SPANS = ("engine.boundary", "fe.admit", "engine.submit", "engine.dispatch",
          "engine.fetch", "engine.postprocess", "engine.spawn", "engine.merge")
 MAX_SIDE = 2
-PROMPT = "plan: [TASK: read the map] [TASK: count the boats] [TASK: name the tides] go"
+# the two sides' tasks are of one length, so both finish in the same drain
+PROMPT = "plan: [TASK: read the map] [TASK: sail the bay] [TASK: name the tides] go"
 
 
 @pytest.fixture(scope="module")
@@ -103,7 +106,9 @@ def test_side_spans_carry_the_river_id(served):
     assert sides and all(s["ids"]["parent"] == river for s in sides)
     spawned = {ev["agent"] for ev in eng.history if ev["event"] == "spawn"}
     merged = {ev["agent"] for ev in eng.history if ev["event"] == "merge"}
-    assert {s["ids"]["agent"] for s in sides if s["name"] == "engine.merge"} == merged
+    # a batch's span names no side: the history does, every spawned side merged
+    assert merged == spawned
+    assert all("agent" not in s["ids"] for s in sides if s["name"] == "engine.merge")
     # a refused spawn has no side to name
     assert {s["ids"].get("agent") for s in sides if s["name"] == "engine.spawn"} == \
         spawned | {None}
@@ -125,7 +130,10 @@ def test_merge_counters_match_the_history(served):
     # the gate's threshold is -1: every thought is let in
     assert all(ev["accepted"] for ev in merges)
     assert eng.stats["merges_accepted"] == eng.stats["merges"]
-    assert sum(1 for s in spans if s["name"] == "engine.merge") == len(merges)
+    # both sides finish in one drain: one batch, one merge program, one span
+    batches = [s for s in spans if s["name"] == "engine.merge"]
+    assert len(batches) == eng.stats["merge_dispatches"] == 1
+    assert batches[0]["ids"]["n"] == len(merges) == MAX_SIDE
 
 
 def test_engine_programs_carry_their_names(served):

@@ -106,6 +106,138 @@ def test_ssm_state_blend():
     np.testing.assert_allclose(w1, 0.7 * w0 + 0.3 * wt, rtol=1e-5, atol=1e-6)
 
 
+def _main_caches(cfg, spec, B, cursors):
+    """Main caches of ``B`` lanes with random contents, each lane's write
+    cursor (``length``, or ``inj_count`` of a synapse) at ``cursors``."""
+    caches = model_lib.init_caches(cfg, B, spec)
+    leaves, tree = jax.tree.flatten(caches)
+    keys = jax.random.split(jax.random.key(7), len(leaves))
+    leaves = [jax.random.normal(k, a.shape, a.dtype) if jnp.issubdtype(a.dtype, jnp.floating)
+              else a for k, a in zip(keys, leaves)]
+    caches = jax.tree.unflatten(tree, leaves)
+    cur = jnp.asarray(cursors, jnp.int32)
+    field = "inj_count" if spec.kind == "synapse" else "length"
+    groups = tuple(
+        dataclasses.replace(g, **{field: jnp.broadcast_to(cur, getattr(g, field).shape)})
+        if hasattr(g, field) else g for g in caches.groups)
+    return dataclasses.replace(caches, groups=groups)
+
+
+# (arch, main cache, cursors, thought length). Every batch holds three
+# thoughts of lane 0 with a rejected one between the two accepted, one of
+# lane 1, and padding rows; "full_cache" pushes both lanes past capacity.
+MERGE_CASES = {
+    "full": ("qwen3-8b", model_lib.CacheSpec(kind="full", capacity=48), [12, 9], 4),
+    "synapse_ring": ("qwen3-8b", model_lib.CacheSpec(kind="synapse", n_landmarks=8, window=8,
+                                                     n_inject=6), [1, 3], 4),
+    "mla": ("deepseek-v2-236b", model_lib.CacheSpec(kind="full", capacity=48), [10, 7], 4),
+    "state_blend": ("rwkv6-1.6b", model_lib.CacheSpec(kind="full", capacity=16), [0, 0], 4),
+    "full_cache": ("qwen3-8b", model_lib.CacheSpec(kind="full", capacity=24), [17, 22], 4),
+}
+
+
+@pytest.mark.parametrize("case", list(MERGE_CASES))
+def test_batched_merge_equals_the_chain_of_single_merges(case):
+    """``merge_thoughts`` over a batch lands every thought where the same
+    merges made one by one with ``merge_thought``, in batch order, would:
+    the same gate, lengths and slots, and the same K/V."""
+    arch, spec, cursors, T = MERGE_CASES[case]
+    cfg, params = _setup(arch)
+    B = 2
+    caches = _main_caches(cfg, spec, B, cursors)
+    vpos_lane = jnp.asarray([100, 140], jnp.int32)
+    thoughts = jax.random.randint(jax.random.key(2), (4, T), 0, cfg.vocab_size)
+    # lane 1's hidden state is its thought's own (score 1); lane 0's three
+    # thoughts are ordered so the one that scores lowest sits between the
+    # other two, and theta falls between it and the next
+    _, th = injection.encode_thought_kv(params, cfg, thoughts, jnp.asarray([100] * 3 + [140]))
+    hidden = jnp.stack([jax.random.normal(jax.random.key(3), (cfg.d_model,)), th[3]])
+    s0 = np.asarray(gate_lib.cosine_score(jnp.broadcast_to(hidden[0], (3, cfg.d_model)), th[:3]))
+    lo, a, b = np.argsort(s0)
+    theta = float(s0[lo] + s0[a]) / 2
+    order = [a, lo, 3, b]
+    parent = [0, 0, 1, 0]
+    K = 8  # padded as the engine pads: four padding rows, aimed at both lanes
+    tokens = jnp.concatenate([thoughts[jnp.asarray(order)],
+                              jax.random.randint(jax.random.key(4), (K - 4, T), 0, cfg.vocab_size)])
+    parent_k = jnp.asarray(parent + [0, 1, 0, 1], jnp.int32)
+    valid = jnp.arange(K) < 4
+
+    merged, accept, score = jax.jit(
+        lambda c, tok, par, vp, ok: injection.merge_thoughts(
+            params, cfg, c, hidden, tok, par, vp, ok, theta),
+    )(caches, tokens, parent_k, vpos_lane[parent_k], valid)
+
+    chain = caches
+    want_accept, want_score = [], []
+    single = jax.jit(lambda c, tok, mask: injection.merge_thought(
+        params, cfg, c, hidden, tok, vpos_lane, mask, theta))
+    for k in range(4):
+        chain, acc, sc = single(chain, jnp.tile(tokens[k][None], (B, 1)),
+                                jnp.arange(B) == parent[k])
+        want_accept.append(bool(acc[parent[k]]))
+        want_score.append(float(sc[parent[k]]))
+
+    assert np.asarray(accept).tolist() == [True, False, True, True] + [False] * 4
+    assert want_accept == [True, False, True, True]
+    np.testing.assert_allclose(np.asarray(score)[:4], want_score, rtol=1e-6, atol=1e-6)
+    for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(merged),
+                                 jax.tree.leaves(chain)):
+        got, want = np.asarray(got), np.asarray(want)
+        if jnp.issubdtype(got.dtype, jnp.floating):
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5,
+                                       err_msg=jax.tree_util.keystr(path))
+        else:  # lengths, counts and the slots' positions: exact
+            np.testing.assert_array_equal(got, want, err_msg=jax.tree_util.keystr(path))
+
+    if case == "full_cache":
+        # a full cache keeps its cursor running past the capacity, and its
+        # last T slots hold the lane's last accepted thought: lane 0 writes
+        # row 0 at 17, then row 3 clamps to 20 over row 0's tail; lane 1's
+        # one thought clamps to 20 too
+        full = merged.groups[0]
+        assert (np.asarray(full.length) == [17 + 2 * T, 22 + T]).all()
+        tk, _ = injection.encode_thought_kv(params, cfg, tokens[:4], vpos_lane[parent_k[:4]])
+        got_k, th_k = np.asarray(full.k), np.asarray(tk.groups[0].k)
+        np.testing.assert_allclose(got_k[:, 0, 17:20], th_k[:, 0, :3], rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(got_k[:, 0, 20:24], th_k[:, 3], rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(got_k[:, 1, 20:24], th_k[:, 2], rtol=1e-5, atol=1e-5)
+        before = np.asarray(caches.groups[0].k)
+        np.testing.assert_array_equal(got_k[:, 0, :17], before[:, 0, :17])
+        np.testing.assert_array_equal(got_k[:, 1, :20], before[:, 1, :20])
+
+
+@pytest.mark.parametrize("cursor,width,last", [
+    ([17, 22], 4, 20),  # a full cache of 24 slots: starts clamp to its last 4
+    ([1, 3], 4, 2),     # an inject ring of 6 slots, 4 a thought
+    ([0, 0], 4, 0),     # a ring a thought fills: the last accepted wins
+])
+def test_merge_blocks_agree_where_they_overlap(cursor, width, last):
+    """A scatter's order among windows that overlap is not defined on the
+    chip, so the blocks of one lane that overlap must carry the same values
+    there: each slot of every accepted row's block copies what the same
+    merges made one by one would leave in that slot."""
+    parent = jnp.asarray([0, 0, 1, 0, 1, 0], jnp.int32)
+    accept = jnp.asarray([True, False, True, True, True, True])
+    start, source, count = injection._placement(
+        parent, accept, jnp.asarray(cursor, jnp.int32), width, last)
+    start, source = np.asarray(start), np.asarray(source)
+    # one by one: each accepted row writes its block at its clamped start
+    want, cur = {}, list(cursor)
+    for k, (p, ok) in enumerate(zip(np.asarray(parent), np.asarray(accept))):
+        if ok:
+            st = min(max(cur[p], 0), last)
+            want.update({(int(p), st + t): k * width + t for t in range(width)})
+            cur[p] += width
+    got = {}
+    for k, (p, ok) in enumerate(zip(np.asarray(parent), np.asarray(accept))):
+        if ok:
+            for t in range(width):
+                got.setdefault((int(p), int(start[k]) + t), set()).add(int(source[k, t]))
+    assert got == {slot: {row} for slot, row in want.items()}
+    assert np.asarray(count).tolist() == [3, 2]
+
+
 # ---------------------------------------------------------------------------
 def test_gate_eq2():
     h = jnp.asarray([[1.0, 0.0], [1.0, 0.0], [1.0, 0.0]])
