@@ -37,10 +37,8 @@ def main():
         # per-lane sampling: freshly spawned streams explore by default...
         side_sampling=SamplingParams(temperature=1.1, top_k=40),
         sync_every=4,  # ...and whole 4-tick windows ride ONE scanned dispatch
-        # quiet drains lengthen the window up to 16 ticks/dispatch, and the
-        # pipelined drain (default) overlaps each window's router/decode
+        # the pipelined drain (default) overlaps each window's router/decode
         # host work with the device's next window
-        max_window=16,
     )
     # ...while river 0 decodes greedily — per-lane params share the dispatch
     engine.submit(
@@ -52,7 +50,7 @@ def main():
     engine.submit("Second river: summarize the meeting notes. [TASK: list action items] ok", lane=1)
 
     for chunk in range(5):  # 5 pipelined chunks == 40 virtual ticks
-        engine.run(8)  # windows lengthen + drains overlap inside each chunk
+        engine.run(8)  # drains overlap inside each chunk
         if chunk % 2 == 1:
             rep = engine.memory_report()
             st = engine.stats
@@ -60,8 +58,7 @@ def main():
                 f"[tick {st['ticks']:3d}] agents={rep['n_agents']} "
                 f"dispatches={st['tick_dispatches']} "
                 f"(ticks/dispatch={st['ticks']/max(st['tick_dispatches'],1):.1f} "
-                f"overlapped_drains={st['overlapped_drains']} "
-                f"windows={st['window_hist']}) "
+                f"overlapped_drains={st['overlapped_drains']}) "
                 f"weights={rep['weight_bytes']/1e6:.1f}MB "
                 f"ctx/agent={rep['context_bytes_per_agent']/1e6:.2f}MB "
                 f"total={rep['total_bytes']/1e6:.1f}MB "

@@ -1,4 +1,4 @@
-"""The Cortex Engine — fused-tick River & Stream topology (DESIGN.md §3).
+"""The Cortex Engine — fused-tick River & Stream topology.
 
 The paper runs the main agent ("River") and side agents ("Streams") on
 concurrent CUDA streams. The TPU-native equivalent is a *device-resident
@@ -32,15 +32,6 @@ scheduler hot loop*:
   order. A failed gate simply falls back to that serial order for one
   window; user-facing control calls (``submit``/``retire_side``/``drain``)
   flush the in-flight window before mutating state.
-* ADAPTIVE WINDOWS: :class:`AdaptiveWindow` lengthens the scan window
-  (``sync_every`` × {1, 2, 4, …} up to ``max_window`` — a small fixed set of
-  lazily jit-cached scan lengths) while drains stay quiet, and snaps back to
-  the base window on any trigger, spawn, merge, or admission. Windows are
-  capped exactly at the serial-path boundary where an active side's step
-  budget completes, and the router's :meth:`~repro.core.router.CortexRouter.
-  plausible` hint (an unclosed ``[`` near the stream end) forces a short
-  window — so control ops land on the same virtual tick as the pinned-window
-  engine.
 * Per-lane sampling: temperature/top-k/top-p live as stacked device arrays
   (:class:`repro.serving.sampler.LaneSampling`) inside ``TickState``, so a
   greedy river can coexist with exploratory streams in the same dispatch and
@@ -55,15 +46,14 @@ scheduler hot loop*:
   finished in the drain, in chunks of at most ``MERGE_BATCH``).
 
 Performance invariants (asserted by tests/test_fused_tick.py,
-tests/test_macro_tick.py, and tests/test_adaptive_pipeline.py):
+tests/test_macro_tick.py, and tests/test_pipelined_drains.py):
   * ``tick()`` issues exactly ONE jitted dispatch;
-  * ``run(n)`` issues exactly ``ceil(n / sync_every)`` jitted dispatches
-    with a pinned window, and **at most** that many with adaptation on;
+  * ``run(n)`` issues exactly ``ceil(n / sync_every)`` jitted dispatches;
   * no blocking host transfer happens outside ``drain()``/``_fetch_rings``;
   * each drain performs exactly one device→host pull of the token rings,
     and the overlapped post-processing region issues ZERO transfers (it
     runs under ``jax.transfer_guard("disallow")`` in the tests);
-  * greedy lanes are bitwise identical between the pipelined/adaptive path,
+  * greedy lanes are bitwise identical between the pipelined path,
     the serial macro path, and the single-tick path, across spawn/merge
     interleavings, and unaffected by other lanes' sampling params.
 """
@@ -130,7 +120,7 @@ def spawn_caches(cfg: ModelConfig, main_caches: model_lib.ModelCaches, spec: mod
     instead of a vmap of L separate passes.
 
     SSM groups: the state is already O(1) — the side agent receives a copy
-    (zero marginal context, noted in DESIGN.md). MLA: latent landmark
+    (zero marginal context). MLA: latent landmark
     selection is future work; sides receive the latent cache as-is.
     """
     groups = []
@@ -479,46 +469,6 @@ def engine_set_side_hidden(hid_a, lane, h):
 _OPEN_BRACKET, _CLOSE_BRACKET = ord("["), ord("]")
 
 
-class AdaptiveWindow:
-    """Window-length policy: lengthen ``sync_every`` while drains are quiet.
-
-    Proposals come from a small fixed ladder ``base * {1, 2, 4, ...}`` capped
-    at ``max_window``, so the engine's lazily jit-cached scan-length variants
-    stay bounded (one compile per rung, ever). The policy climbs one rung per
-    quiet drain — no router trigger, no spawn/merge/completion, no admission
-    — and snaps back to the base window on any such event, restoring the
-    trigger-reaction latency of the pinned engine the moment control traffic
-    reappears. ``max_window == base`` degenerates to the pinned policy.
-    """
-
-    def __init__(self, base: int, max_window: int | None = None):
-        self.base = max(1, base)
-        requested = max(self.base, max_window or self.base)
-        # every rung must be base * 2^k: the engine's boundary math (side
-        # budget caps, drain alignment with the pinned reference) assumes
-        # windows are base multiples, so a max_window that is not on the
-        # ladder rounds DOWN to the largest rung below it
-        ladder = [self.base]
-        while ladder[-1] * 2 <= requested:
-            ladder.append(ladder[-1] * 2)
-        self.ladder = tuple(ladder)
-        self.max_window = ladder[-1]
-        self._rung = 0
-
-    @property
-    def adaptive(self) -> bool:
-        return len(self.ladder) > 1
-
-    def propose(self) -> int:
-        return self.ladder[self._rung]
-
-    def on_quiet_drain(self):
-        self._rung = min(self._rung + 1, len(self.ladder) - 1)
-
-    def on_event(self):
-        self._rung = 0
-
-
 @dataclass
 class AgentView:
     """Host-side bookkeeping for one agent lane (refreshed at drain time)."""
@@ -576,7 +526,6 @@ class CortexEngine:
         side_sampling: SamplingParams | None = None,
         seed: int = 0,
         sync_every: int = 1,
-        max_window: int | None = None,
         pipeline: bool = True,
         side_prompt_cap: int = 64,
         compute_dtype: str | None = None,
@@ -611,17 +560,8 @@ class CortexEngine:
         self.side_sampling = side_sampling if side_sampling is not None else sampling
         self.sync_every = max(1, sync_every)
         self.side_prompt_cap = side_prompt_cap
-        # Adaptive windows: ``run`` may scan up to max_window virtual ticks
-        # per dispatch while drains stay quiet (max_window=None pins the
-        # window at sync_every; off-ladder values round DOWN to base*2^k).
-        # ``pipeline=False`` keeps the serial PR 4 dispatch→drain→dispatch
-        # order — the parity reference in tests — whose windows stay pinned,
-        # so adaptation is dropped there rather than paying max_window-sized
-        # rings and router tail for a policy that never engages.
-        self.window = AdaptiveWindow(
-            self.sync_every, max_window if pipeline else None
-        )
-        self.max_window = self.window.max_window
+        # ``pipeline=False`` keeps the serial dispatch→drain→dispatch order:
+        # the parity reference in tests
         self.pipeline = pipeline
         # macro windows mean bigger drain chunks: size the router's overlap
         # tail so a tag split across window boundaries still matches. The
@@ -630,7 +570,7 @@ class CortexEngine:
         # (b) a full drain window of text (8 bytes/token bounds the worst
         # UTF-8 replacement expansion). tests/test_router.py pins this.
         self.router = CortexRouter(
-            tail=max(256, 8 * self.max_window, side_prompt_cap + 16)
+            tail=max(256, 8 * self.sync_every, side_prompt_cap + 16)
         )
 
         # lane mesh: detect the axis up front — the side spec's attend policy
@@ -703,10 +643,9 @@ class CortexEngine:
         self.stats = {
             "ticks": 0, "tick_dispatches": 0, "macro_dispatches": 0,
             "aux_dispatches": 0, "host_syncs": 0, "drains": 0,
-            # pipeline/adaptive telemetry: drains whose host post-processing
-            # overlapped the next window's device execution, and a histogram
-            # of dispatched window lengths (window_hist[w] = count)
-            "overlapped_drains": 0, "window_hist": {},
+            # pipeline telemetry: drains whose host post-processing
+            # overlapped the next window's device execution
+            "overlapped_drains": 0,
             # sides started, and [TASK] triggers refused for want of a lane
             "spawns": 0, "spawns_dropped": 0,
             # sides merged back, merges whose thought the gate let in, and
@@ -728,10 +667,9 @@ class CortexEngine:
         # inside decode becomes an identity XLA elides). The Prism's master
         # copy stays authoritative for accounting/training.
         self._params = model_lib.cast_params(prism.params, cfg)
-        # rings must hold the longest adaptive window, not just sync_every
         self.state = init_tick_state(
             cfg, n_main=n_main, max_side=max_side, main_spec=self.main_spec,
-            side_spec=self.side_spec, ring_capacity=self.max_window,
+            side_spec=self.side_spec, ring_capacity=self.sync_every,
             side_prompt_cap=side_prompt_cap, main_sampling=self.sampling,
             side_sampling=self.side_sampling, seed=seed,
         )
@@ -928,7 +866,6 @@ class CortexEngine:
         :meth:`hibernate` and :meth:`wake` into a different lane); omitted,
         the classic per-lane identity ``main{lane}`` is used when free."""
         self.drain()  # align host mirrors to a window boundary
-        self.window.on_event()  # admission: back to the base window
         aid = self._claim_main_identity(lane, agent_id)
         with _span("engine.submit", agent=aid):
             ids = self.tok.encode(prompt, bos=True)
@@ -1051,9 +988,9 @@ class CortexEngine:
         self.drain()
 
     def _dispatch_window(self, n: int):
-        """Advance ``n <= max_window - pending`` virtual ticks in one
+        """Advance ``n <= sync_every - pending`` virtual ticks in one
         dispatch. No drain, no host sync — callers close the window."""
-        assert self._pending + n <= self.max_window
+        assert self._pending + n <= self.sync_every
         with _span("engine.dispatch", n=n):
             step_sides = any(s.active for s in self.sides)
             fn = self._macro_fn(n, step_sides, *self._sampler_flags(step_sides))
@@ -1062,50 +999,7 @@ class CortexEngine:
         self.stats["tick_dispatches"] += 1
         if n > 1:
             self.stats["macro_dispatches"] += 1
-        hist = self.stats["window_hist"]
-        hist[n] = hist.get(n, 0) + 1
         self._pending += n
-
-    def _next_window(self, remaining: int, pending=None) -> int:
-        """Length of the next scan window: the adaptive proposal, capped (a)
-        exactly at the serial-path boundary where any active side's step
-        budget completes — a multiple of the base window, so the merge lands
-        on the same virtual tick as the pinned engine — and (b) to the base
-        window whenever the router's retained tail holds an unclosed ``[``
-        (a tag may be completing: keep reaction latency at one base window).
-        Every cap keeps the window a multiple of the base except the run's
-        trailing partial window (``remaining``).
-
-        ``pending=(rings, n)`` is the overlapped-branch correction: window
-        *t* has been fetched but NOT yet post-processed, so the side views'
-        ``tokens``/``steps`` are one window stale — the budget cap must
-        count window *t*'s recorded ring tokens or the boundary lands one
-        window late and the merge drifts off the serial tick (the router
-        tail, by contrast, is provably unchanged by a gate-approved window:
-        no ``[`` entered it and no pending ``[`` was closed)."""
-        base = self.sync_every
-        w = self.window.propose()
-        if w > base:
-            for s in self.sides:
-                if not s.active:
-                    continue
-                generated = len(s.tokens) - s.prompt_len
-                steps = s.steps
-                if pending is not None:
-                    rings, p_n = pending
-                    toks = rings[1][s.lane, :p_n]
-                    generated += int((toks >= 0).sum())
-                    steps += p_n
-                forced_left = max(0, (s.prompt_len - 1) - steps)
-                t_budget = forced_left + max(1, self.side_max_steps - generated)
-                boundary = base * -(-t_budget // base)  # ceil to base multiple
-                w = min(w, boundary)
-            if any(
-                self.router.plausible(a.agent_id)
-                for a in (*self.mains, *self.sides) if a.active
-            ):
-                w = base
-        return min(w, remaining)
 
     def _gate(self, rings, n: int) -> bool:
         """May window ``t+1`` be dispatched BEFORE window ``t``'s host
@@ -1148,9 +1042,8 @@ class CortexEngine:
         return True
 
     def run(self, n_ticks: int):
-        """Advance ``n_ticks`` virtual ticks in at most
-        ``ceil(n_ticks/sync_every)`` dispatches (exactly that many with a
-        pinned window; adaptive windows need fewer).
+        """Advance ``n_ticks`` virtual ticks in ``ceil(n_ticks/sync_every)``
+        dispatches.
 
         Pipelined (default): after fetching window *t*'s rings — the one
         blocking sync per window — the conservative :meth:`_gate` decides
@@ -1183,7 +1076,7 @@ class CortexEngine:
                 if not self._any_active():
                     self.stats["ticks"] += remaining
                     return
-                inflight = self._next_window(remaining)
+                inflight = min(self.sync_every, remaining)
                 self._dispatch_window(inflight)
                 self._prefetch_rings()
                 remaining -= inflight
@@ -1197,10 +1090,8 @@ class CortexEngine:
             self._commit_ready_wakes(mark_fresh=True)
             if remaining and self._any_active() and self._gate(rings, nwin):
                 # overlap: the device starts window t+1 while the host does
-                # window t's decoding/router work (guaranteed control-free);
-                # the window policy must see window t's still-unprocessed
-                # ring tokens or its budget caps run one window stale
-                inflight = self._next_window(remaining, pending=(rings, nwin))
+                # window t's decoding/router work (guaranteed control-free)
+                inflight = min(self.sync_every, remaining)
                 self._dispatch_window(inflight)
                 self._prefetch_rings()
                 remaining -= inflight
@@ -1277,7 +1168,6 @@ class CortexEngine:
         with _span("engine.postprocess", overlapped=overlapped):
             main_ring, side_ring = rings
             self.stats["drains"] += 1
-            quiet = True
 
             # 1. rivers: append the window's tokens. Decode is INCREMENTAL
             # (ISSUE 9 bugfix): a multi-byte codepoint split across the drain
@@ -1320,9 +1210,10 @@ class CortexEngine:
                 s.text += chunk
                 if self.stream_tap is not None and raw:
                     self.stream_tap(s, chunk, raw)
-                all_trig = self.router.feed(s.agent_id, chunk)
-                quiet = quiet and not all_trig
-                trig = [t for t in all_trig if t.kind in ("done", "answer")]
+                trig = [
+                    t for t in self.router.feed(s.agent_id, chunk)
+                    if t.kind in ("done", "answer")
+                ]
                 generated = len(s.tokens) - s.prompt_len
                 if trig or generated >= self.side_max_steps:
                     # end of this stream: flush the decoder so s.text equals
@@ -1345,24 +1236,16 @@ class CortexEngine:
             assert not (overlapped and finished), "pipeline gate violated: merge"
             if finished:
                 self._merge_sides(finished)
-            quiet = quiet and not finished
 
             # 4. river triggers spawn new streams
             for m in self.mains:
                 if not m.active or m.lane not in main_chunks:
                     continue
                 for tr in self.router.feed(m.agent_id, main_chunks[m.lane]):
-                    quiet = False
                     assert not overlapped, "pipeline gate violated: trigger"
                     if tr.kind == "task":
                         self._spawn_side(m, tr.payload)
 
-            # 5. window policy: quiet drains earn longer windows, any control
-            # event snaps back to the base window
-            if quiet:
-                self.window.on_quiet_drain()
-            else:
-                self.window.on_event()
             self._fresh_wakes.clear()  # next window has the woken lanes on device
 
     # ------------------------------------------------------------------
@@ -1439,7 +1322,6 @@ class CortexEngine:
         if not s.active:
             return
         self.drain()
-        self.window.on_event()  # composition change: back to the base window
         act_a = self._jit_retire_side(self.state.side_active, lane)
         self.state = dataclasses.replace(self.state, side_active=act_a)
         self.stats["aux_dispatches"] += 1
@@ -1465,7 +1347,6 @@ class CortexEngine:
                 f"target it for their merge"
             )
         self.drain()
-        self.window.on_event()  # composition change: back to the base window
         act_a = self._jit_retire_main(self.state.main_active, lane)
         self.state = dataclasses.replace(self.state, main_active=act_a)
         self.stats["aux_dispatches"] += 1
@@ -1527,7 +1408,6 @@ class CortexEngine:
                 f"main lane {lane} for their merge"
             )
         self.drain()  # boundary-align: no mid-window host syncs
-        self.window.on_event()
         if kind == "main":
             snap = self._jit_gather_main(self.state, lane)
             act_a = self._jit_retire_main(self.state.main_active, lane)
@@ -1728,7 +1608,6 @@ class CortexEngine:
         bound = self.registry.bind(agent_id, lane)
         bound.bound_tick = self.stats["ticks"]
         self.store.drop(agent_id)
-        self.window.on_event()
         if mark_fresh:
             # a fetched-but-unprocessed window exists: this lane was not on
             # device for it, so its mirror advancement must be skipped once

@@ -38,13 +38,12 @@ class CortexRouter:
     retained overlap, and is then silently missed — so the engine must size
     ``tail`` at least as large as the longest tag it can round-trip
     (``[TASK: <side_prompt_cap bytes>]`` plus framing) and at least one full
-    drain window of text (``8 * max_window`` bytes covers the worst-case
+    drain window of text (``8 * sync_every`` bytes covers the worst-case
     UTF-8 expansion). tests/test_router.py pins both sides of this contract.
 
     :meth:`plausible` is the pipelined engine's trigger-plausibility hint: an
     unclosed ``[`` in the retained tail means the next drained chunk could
-    complete a tag, so the adaptive-window policy must keep the window short
-    and the pipelined drain must process that lane serially.
+    complete a tag, so the pipelined drain must process that lane serially.
     """
 
     def __init__(self, tail: int = 256):

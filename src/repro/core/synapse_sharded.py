@@ -1,7 +1,7 @@
-"""Sharding-aware primitives for the streaming synapse decode (§Perf).
+"""Sharding-aware primitives for the streaming synapse decode.
 
-Two findings from the hillclimb drive this module (EXPERIMENTS.md §Perf,
-pair qwen3-8b x long_500k):
+Two properties of GSPMD on token-sharded synapse buffers (seen lowering
+qwen3-8b at the long_500k shape) drive this module:
 
 1. GSPMD turns dynamic-index scatter/gather on token-sharded synapse buffers
    into "involuntary full rematerialization" (replicate -> scatter ->

@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache, as every entry point turns it on.
 
 A cold compile of the 24-layer macro window is a large share of a short
-run, so ``chip_smoke.py``, ``launch/serve.py`` and ``benchmarks/run.py``
+run, so ``chip_smoke.py``, ``launch/serve.py`` and ``bench/cell.py``
 keep compiled programs on disk. The directory is ``JAX_COMPILATION_CACHE_DIR``
 when that is set, and otherwise the fixed ``<repo>/.jax_cache`` (ignored by
 git): never a temporary, per-process or per-run path, since a directory that

@@ -512,7 +512,7 @@ def main():
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--both-meshes", action="store_true")
-    ap.add_argument("--out", default="benchmarks/artifacts/dryrun")
+    ap.add_argument("--out", default="artifacts/dryrun")
     ap.add_argument("--lane", type=int, default=None, metavar="N_SIDE",
                     help="lower+compile the lane-sharded macro tick at N_SIDE "
                          "side lanes on an 8-device lane mesh (ISSUE 6 scale "

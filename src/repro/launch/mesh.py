@@ -8,11 +8,6 @@ from __future__ import annotations
 import jax
 from jax.sharding import AxisType
 
-# hardware constants for the roofline (TPU v5e)
-PEAK_FLOPS_BF16 = 197e12   # per chip
-HBM_BW = 819e9             # bytes/s per chip
-ICI_BW = 50e9              # bytes/s per link
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)

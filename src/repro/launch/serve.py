@@ -16,8 +16,8 @@
 Requests stream: decoded chunks print as the backend commits them (bitwise
 identical to the end-of-run decode — the incremental UTF-8 decoder), and a
 final per-tenant SLO summary (TTFT, time-per-output-token, p50/p99 tick
-latency, token shares, fairness counters) mirrors what
-benchmarks/bench_serving.py records.
+latency, token shares, fairness counters) is
+:meth:`ServingFrontend.metrics`.
 
 Crash recovery (ISSUE 8): point ``--cold-dir`` at a persistent directory
 and a later run with ``--recover`` rebuilds the cold tier from disk

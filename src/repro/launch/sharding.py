@@ -1,7 +1,7 @@
 """Sharding rules: PartitionSpec trees for params, optimizer state, batches
 and caches, with divisibility-aware fallback.
 
-Baseline scheme (hillclimbed in EXPERIMENTS.md §Perf):
+Baseline scheme:
   * FSDP over the ("pod","data") axes on the input dim of every matrix,
   * tensor parallel over "model" on the heads/ffn/expert dim,
   * experts sharded over "model" (expert parallelism),
@@ -251,7 +251,7 @@ def cache_specs(caches_abstract, cfg: ModelConfig, mesh: Mesh, *, synapse_token_
 
     synapse_token_shard=False: landmark/window/inject buffers replicate their
     token dim (they are O(K+W+J) small; sharding it forces a per-step
-    all-gather of every synapse buffer — §Perf hillclimb finding).
+    all-gather of every synapse buffer).
     """
     dp = tuple(a for a in mesh.axis_names if a in ("pod", "data"))
     tp = "model"

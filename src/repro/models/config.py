@@ -84,7 +84,7 @@ class ModelConfig:
     remat: bool = True
     remat_policy: str = "full"   # full | dots (save MXU outputs, skip recompute)
     moe_dispatch: str = "per_lane"  # per_lane (shardable sort) | global
-    scan_layers: bool = True  # False: python-unrolled stacks (roofline probes)
+    scan_layers: bool = True  # False: python-unrolled stacks
 
     # ------------------------------------------------------------------
     def __post_init__(self):

@@ -235,8 +235,8 @@ def _shared_attn_fwd(p, cfg: ModelConfig, x, positions, lora_idx, chunk):
 
 
 def _scan_stack(body, carry, xs, count: int, use_scan: bool):
-    """lax.scan or python-unrolled equivalent (roofline probes unroll so
-    cost_analysis sees every layer instead of one while body)."""
+    """lax.scan or python-unrolled equivalent (the engine unrolls stacks of
+    at most 8 layers; unrolled, cost_analysis also sees every layer)."""
     if use_scan:
         return jax.lax.scan(body, carry, xs)
     ys = []

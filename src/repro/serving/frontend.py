@@ -36,8 +36,7 @@ Three pieces:
   Per-request SLO metrics (TTFT, time-per-output-token, queue wait) and
   per-tenant aggregates, plus p50/p99 tick latency sampled once per
   :meth:`ServingFrontend.step` chunk, come out of
-  :meth:`ServingFrontend.metrics` and are recorded in BENCH_throughput.json's
-  ``serving`` section by benchmarks/bench_serving.py.
+  :meth:`ServingFrontend.metrics`.
 
 The wire protocol lives in :mod:`repro.serving.transport` (ISSUE 10): an
 HTTP/1.1 + SSE server that maps ``POST /v1/generate`` onto :meth:`submit`

@@ -42,8 +42,9 @@ dispatch counts, never flushing a pipelined window) change on the wire
 path.
 
 A minimal stdlib client (:class:`SSEClient`, :func:`generate_sync`,
-:func:`http_json`) lives here too — tests and benchmarks drive the
-loopback with it, and it doubles as protocol documentation.
+:func:`http_json`) lives here too — tests, ``chip_smoke.py`` and
+``launch/serve.py`` drive the loopback with it, and it doubles as
+protocol documentation.
 """
 from __future__ import annotations
 
@@ -339,7 +340,7 @@ class _Handler(BaseHTTPRequestHandler):
 
 
 # ---------------------------------------------------------------------------
-# minimal stdlib client — tests, benchmarks, and protocol documentation
+# minimal stdlib client — tests, launchers, and protocol documentation
 # ---------------------------------------------------------------------------
 
 class SSEClient:

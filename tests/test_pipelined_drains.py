@@ -1,22 +1,18 @@
-"""Adaptive macro windows + pipelined drains (ISSUE 5 acceptance criteria).
+"""Pipelined drains over ``sync_every`` macro windows.
 
 The contract this suite pins down:
 
 * CHURN PARITY — sequences of submit/spawn/merge/retire interleaved with
   ``run(n)`` produce bitwise-identical greedy token streams (main AND side)
-  and identical control-plane histories on the pipelined-pinned and the
-  pipelined-adaptive engines vs the serial PR 4 reference
-  (``pipeline=False``), including partial windows and lane restarts;
-* DISPATCH ACCOUNTING — ``run(n)`` from a boundary issues at most
-  ``ceil(n / sync_every)`` dispatches, exactly that many when adaptation is
-  off, and the window histogram's tick mass equals the ticks advanced;
+  and identical control-plane histories on the pipelined engine vs the
+  serial reference (``pipeline=False``), including partial windows and
+  lane restarts;
+* DISPATCH ACCOUNTING — ``run(n)`` from a boundary issues exactly
+  ``ceil(n / sync_every)`` dispatches and counts every tick it advanced;
 * OVERLAP — the pipelined drain's post-processing region (router scan,
   UTF-8 decode, bookkeeping) issues ZERO device transfers while the next
   window executes — enforced with ``jax.transfer_guard("disallow")``, not
   just the engine's self-reported counters;
-* ADAPTATION — trigger-free drains climb the window ladder to
-  ``max_window``; any admission/trigger/merge snaps back to the base
-  window; scan-length jit variants stay bounded by the fixed ladder;
 * SERVER — BatchServer's pipelined decode matches its serial loop bitwise,
   and a recycled lane never inherits the previous request's sampling params
   (the samp cache invalidates on every composition change).
@@ -50,7 +46,7 @@ def setup():
     return cfg, params
 
 
-def _engine(cfg, params, *, pipeline, max_window=None, sync_every=4,
+def _engine(cfg, params, *, pipeline, sync_every=4,
             side_max_steps=6, sampling=SamplingParams(greedy=True),
             side_sampling=None):
     prism = Prism(params, cfg)
@@ -59,7 +55,7 @@ def _engine(cfg, params, *, pipeline, max_window=None, sync_every=4,
         prism, tok, n_main=1, max_side=2, main_capacity=128,
         side_max_steps=side_max_steps, inject_tokens=8, theta=-1.0,
         sampling=sampling, side_sampling=side_sampling,
-        sync_every=sync_every, max_window=max_window, pipeline=pipeline,
+        sync_every=sync_every, pipeline=pipeline,
     )
 
 
@@ -99,7 +95,7 @@ CHURN_SCRIPT = [
     ("retire", 1),
     ("run", 5),
     ("submit", "calm text with no tags at all"),  # lane restart
-    ("run", 24),              # trigger-free stretch: windows may lengthen
+    ("run", 24),              # trigger-free stretch: drains overlap
     ("run", 3),
 ]
 
@@ -110,103 +106,57 @@ def churn(setup):
     engines = {
         "serial": _engine(cfg, params, pipeline=False),
         "pinned": _engine(cfg, params, pipeline=True),
-        "adaptive": _engine(cfg, params, pipeline=True, max_window=16),
     }
     deltas = {k: _apply(e, CHURN_SCRIPT) for k, e in engines.items()}
     return engines, deltas
 
 
 def test_churn_parity_bitwise(churn):
-    """Pipelined (pinned AND adaptive) == serial PR 4 path, token-for-token
-    and event-for-event, across spawn/merge/retire churn."""
+    """Pipelined == serial path, token-for-token and event-for-event,
+    across spawn/merge/retire churn."""
     engines, _ = churn
     ref = _streams(engines["serial"])
     assert _streams(engines["pinned"]) == ref
-    assert _streams(engines["adaptive"]) == ref
     # the script actually exercised the control plane
     events = [e for e, _ in ref[2]]
     assert "spawn" in events and "merge" in events and "retire" in events
 
 
 def test_churn_dispatch_accounting(churn):
-    """Per run(n) from a boundary: pinned issues exactly ceil(n/base)
-    dispatches, adaptive at most that many (and fewer over the whole
-    script, or it never adapted)."""
+    """Per run(n) from a boundary: the pipelined engine issues exactly
+    ceil(n/sync_every) dispatches."""
     engines, deltas = churn
     for n, d in deltas["pinned"]:
         assert d == math.ceil(n / 4), (n, d)
-    for n, d in deltas["adaptive"]:
-        assert d <= math.ceil(n / 4), (n, d)
-    total_pinned = sum(d for _, d in deltas["pinned"])
-    total_adaptive = sum(d for _, d in deltas["adaptive"])
-    assert total_adaptive < total_pinned
     # serial and pinned agree exactly (pipelining reorders host work only)
     assert deltas["serial"] == deltas["pinned"]
 
 
-def test_churn_window_hist_accounts_every_tick(churn):
+def test_churn_accounts_every_tick(churn):
     engines, _ = churn
+    asked = sum(op[1] for op in CHURN_SCRIPT if op[0] == "run")
     for eng in engines.values():
-        hist = eng.stats["window_hist"]
-        assert sum(w * c for w, c in hist.items()) == eng.stats["ticks"]
-    assert max(engines["adaptive"].stats["window_hist"]) > 4   # lengthened
-    assert max(engines["pinned"].stats["window_hist"]) == 4    # pinned
+        assert eng.stats["ticks"] == asked
     assert engines["serial"].stats["overlapped_drains"] == 0
     assert engines["pinned"].stats["overlapped_drains"] > 0
-    assert engines["adaptive"].stats["overlapped_drains"] > 0
-
-
-def test_adaptive_ladder_is_bounded_and_snaps_back(setup):
-    cfg, params = setup
-    eng = _engine(cfg, params, pipeline=True, max_window=16)
-    assert eng.window.ladder == (4, 8, 16)
-    eng.submit("calm words only", lane=0)
-    assert eng.window.propose() == 4  # admission resets
-    eng.run(48)
-    hist = eng.stats["window_hist"]
-    assert hist.get(16, 0) >= 1, hist  # climbed to max_window
-    assert eng.stats["tick_dispatches"] < math.ceil(48 / 4)
-    # any admission snaps the proposal back to the base window
-    eng.submit("another calm prompt", lane=0)
-    assert eng.window.propose() == 4
-    # the jit cache stays bounded by ladder rungs x variants (+ partials)
-    lengths = {k[0] for k in eng._jit_macro}
-    assert lengths <= {1, 3, 4, 8, 16}, lengths
 
 
 def test_overlapped_budget_cap_sees_pending_window(setup):
-    """Regression: in the overlapped branch the window policy runs BEFORE
-    window t's post-processing, so the side step-budget cap must count
-    window t's still-unprocessed ring tokens — with stale counters the
-    boundary lands one window late, the merge drifts off the serial tick,
-    and the main stream diverges (observed at sync_every=2, max_window=16,
-    side_max_steps=9 before the fix)."""
+    """In the overlapped branch the gate runs BEFORE window t's
+    post-processing, so its side step-budget check must count window t's
+    still-unprocessed ring tokens. An odd budget over a 2-tick window ends
+    mid-window: the merge must land on the serial engine's tick, or the
+    main stream diverges."""
     cfg, params = setup
     kw = dict(sync_every=2, side_max_steps=9)
     serial = _engine(cfg, params, pipeline=False, **kw)
-    adaptive = _engine(cfg, params, pipeline=True, max_window=16, **kw)
-    for eng in (serial, adaptive):
+    pinned = _engine(cfg, params, pipeline=True, **kw)
+    for eng in (serial, pinned):
         eng.submit("hello [TASK: go] world", lane=0)
         eng.run(48)
-    assert _streams(adaptive) == _streams(serial)
+    assert _streams(pinned) == _streams(serial)
     assert any(e == "merge" for e, _ in _streams(serial)[2])
-    assert max(adaptive.stats["window_hist"]) > 2  # windows did lengthen
-
-
-def test_max_window_rounds_down_to_a_ladder_rung(setup):
-    """A max_window that is not base*2^k would put drain boundaries off the
-    base-multiple grid every serial invariant assumes — the ladder rounds
-    it down instead (and the rings are sized to the effective rung)."""
-    from repro.core.engine import AdaptiveWindow
-
-    assert AdaptiveWindow(8, 12).ladder == (8,)
-    assert AdaptiveWindow(8, 12).max_window == 8
-    assert AdaptiveWindow(4, 17).ladder == (4, 8, 16)
-    assert AdaptiveWindow(2, 16).ladder == (2, 4, 8, 16)
-    cfg, params = setup
-    eng = _engine(cfg, params, pipeline=True, sync_every=4, max_window=13)
-    assert eng.max_window == 8
-    assert eng.state.main_ring.shape[1] == 8  # ring capacity matches
+    assert pinned.stats["overlapped_drains"] > 0
 
 
 def test_overlap_region_issues_no_transfers(setup):
@@ -270,25 +220,24 @@ def test_gate_is_conservative_on_trigger_bytes(setup):
     eng.drain()
 
 
-def test_mixed_sampling_lanes_inside_adaptive_windows(setup):
-    """Greedy river + filtered stochastic streams sharing one lengthened
-    scan window: every lane's draws — greedy AND filtered — are bitwise
-    identical to the serial fixed-window reference (the shared sampling
-    pass is stable across window groupings because the PRNG splits per
-    virtual tick and the static sampler flags only change at drains)."""
+def test_mixed_sampling_lanes_inside_macro_windows(setup):
+    """Greedy river + filtered stochastic streams sharing one scanned
+    window: every lane's draws — greedy AND filtered — are bitwise
+    identical to the serial reference (the PRNG splits per virtual tick
+    and the static sampler flags only change at drains, so the pipelined
+    order of host work cannot move a draw)."""
     cfg, params = setup
     kw = dict(side_max_steps=12,
               side_sampling=SamplingParams(temperature=1.1, top_k=12))
     serial = _engine(cfg, params, pipeline=False, **kw)
-    adaptive = _engine(cfg, params, pipeline=True, max_window=16, **kw)
-    for eng in (serial, adaptive):
+    pinned = _engine(cfg, params, pipeline=True, **kw)
+    for eng in (serial, pinned):
         eng.submit("mixed [TASK: explore] lanes", lane=0)
         eng.run(28)
-    assert _streams(adaptive) == _streams(serial)
-    side = next(s for s in adaptive.sides if s.tokens)
+    assert _streams(pinned) == _streams(serial)
+    side = next(s for s in pinned.sides if s.tokens)
     assert len(side.tokens) > side.prompt_len       # stochastic lane ran
-    assert max(adaptive.stats["window_hist"]) > 4   # windows actually grew
-    assert any(e == "merge" for e, _ in _streams(adaptive)[2])
+    assert any(e == "merge" for e, _ in _streams(pinned)[2])
 
 
 def test_batchserver_pipeline_matches_serial(setup):
@@ -349,10 +298,8 @@ _PROP = {}  # kind -> engine, reused across examples (jit caches are hot)
 def _prop_engine(setup, kind):
     cfg, params = setup
     if kind not in _PROP:
-        pipeline = kind != "serial"
-        max_window = 16 if kind == "adaptive" else None
-        _PROP[kind] = _engine(cfg, params, pipeline=pipeline,
-                              max_window=max_window, side_max_steps=4)
+        _PROP[kind] = _engine(cfg, params, pipeline=kind != "serial",
+                              side_max_steps=4)
     eng = _PROP[kind]
     for s in eng.sides:  # clear streams left over from the previous example
         if s.active:
@@ -377,21 +324,17 @@ _OP = st.one_of(
 )
 def test_property_churn_parity(setup, prompt, with_task, ops):
     """Randomized lane churn: submit/spawn/merge/retire interleaved with
-    run(n) — pipelined pinned AND adaptive engines must equal the serial
-    reference token-for-token (main and side lanes) with at most the serial
-    dispatch count."""
+    run(n) — the pipelined engine must equal the serial reference
+    token-for-token (main and side lanes) with the same dispatch count."""
     script = [("submit", prompt + (" [TASK: check] tail" if with_task else ""))]
     script += list(ops)
     results, deltas = {}, {}
-    for kind in ("serial", "pinned", "adaptive"):
+    for kind in ("serial", "pinned"):
         eng = _prop_engine(setup, kind)
         h0 = len(eng.history)
         deltas[kind] = _apply(eng, script)
         m, sides, hist = _streams(eng)
         results[kind] = (m, sides, hist[h0:])
     assert results["pinned"] == results["serial"]
-    assert results["adaptive"] == results["serial"]
     for (n, d_pin), (_, d_ser) in zip(deltas["pinned"], deltas["serial"]):
         assert d_pin == d_ser == math.ceil(n / 4)
-    for n, d in deltas["adaptive"]:
-        assert d <= math.ceil(n / 4)

@@ -662,6 +662,68 @@ def test_wake_deadline_degrades_blocked_promotion(setup):
     assert eng.wake("alice", wait=True).active  # second attempt lands
 
 
+@pytest.mark.filterwarnings("ignore::pytest.PytestUnhandledThreadExceptionWarning")
+def test_scripted_fault_storm_against_one_engine(setup, tmp_path):
+    """Every fault kind in one engine, in one script: a bit-flipped cold
+    blob, transient read failures and a killed prefetch worker against
+    hibernate/wake churn. Each agent degrades on its own (permanent loss
+    -> LOST, transient -> retried or re-woken), the engine keeps ticking,
+    and the untouched lane stays bitwise equal to a fault-free engine."""
+    cfg, params = setup
+    tok = ByteTokenizer(cfg.vocab_size)
+    prompts = {"A": "agent A considers the first question at length.",
+               "B": "agent B writes a careful second answer here.",
+               "C": "agent C is the untouched control stream."}
+
+    def build(store=None):
+        eng = CortexEngine(Prism(params, cfg), tok, n_main=3, max_side=2,
+                           main_capacity=128, theta=1e9, sync_every=4,
+                           sampling=SamplingParams(greedy=True), store=store)
+        for lane, (aid, p) in enumerate(prompts.items()):
+            eng.submit(p, lane=lane, agent_id=aid)
+        return eng
+
+    ref = build()
+    ref.run(32)
+    ref_c = next(m for m in ref.mains if m.agent_id == "C").text
+
+    faults = (
+        FaultInjector()
+        .flip_write("A")                          # permanent: A's blob corrupt on disk
+        .fail_read("B", nth=1, times=2)           # transient: first wake retries through
+        .kill_worker_on_read("B", nth=4)          # second wake kills the worker
+    )
+    store = _cold_store(tmp_path, faults=faults)
+    eng = build(store)
+    eng.run(16)
+    eng.hibernate("A")
+    eng.hibernate("B")
+    eng.wake("A")   # corrupt blob -> quarantine -> LOST; engine keeps ticking
+    eng.wake("B")   # two injected read failures -> retry -> lands
+    eng.run(8)
+    eng.flush_wakes()
+    assert eng.registry.get("A").status == LOST, eng.registry.get("A").status
+    assert eng.registry.get("B").status == ACTIVE, eng.registry.get("B").status
+    assert store.stats["quarantined"] == 1 and store.stats["wake_retries"] == 2, store.stats
+    # round 2: the prefetch worker dies mid-promotion; supervision must fail
+    # the ticket (B stays HIBERNATED, re-wakeable), respawn, then succeed
+    eng.hibernate("B")
+    eng.wake("B")
+    eng.run(4)
+    eng.flush_wakes()
+    assert eng.registry.get("B").status == HIBERNATED, eng.registry.get("B").status
+    assert eng.stats["wake_failures"] >= 1 and store.stats["worker_respawns"] == 1
+    eng.wake("B", wait=True)
+    eng.run(4)
+    eng.flush_wakes()
+    assert eng.registry.get("B").status == ACTIVE
+    # the control lane never noticed any of it: bitwise parity at tick 32
+    assert eng.stats["ticks"] == 32, eng.stats["ticks"]
+    chaos_c = next(m for m in eng.mains if m.agent_id == "C").text
+    assert chaos_c == ref_c, (chaos_c[:60], ref_c[:60])
+    assert eng.stats["lost_agents"] == 1 and eng.stats["wakes"] == 2
+
+
 def test_fault_injected_wake_overlap_region_zero_transfers(setup):
     """The acceptance bar's zero-transfer invariant UNDER fault injection:
     a wake that retried through transient faults commits between the ring

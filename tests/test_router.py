@@ -76,28 +76,26 @@ def test_tag_longer_than_tail_is_missed_documented():
 def test_engine_sizes_tail_for_its_longest_tag_and_window():
     """Engine-side of the contract: the router tail covers the longest tag
     the engine round-trips ('[TASK: ' + side_prompt_cap bytes + ']') and a
-    full max_window drain of text."""
+    full sync_every drain of text."""
     cfg = dataclasses.replace(
         get_config("qwen2.5-0.5b", reduced=True), compute_dtype="float32"
     )
     params = model_lib.init_params(jax.random.key(0), cfg)
     tok = ByteTokenizer(cfg.vocab_size)
-    for sync_every, max_window, cap in ((1, None, 64), (8, 64, 64), (4, 16, 200)):
+    for sync_every, cap in ((1, 64), (64, 64), (4, 200)):
         eng = CortexEngine(
             Prism(params, cfg), tok, n_main=1, max_side=1,
-            sync_every=sync_every, max_window=max_window,
-            side_prompt_cap=cap,
+            sync_every=sync_every, side_prompt_cap=cap,
         )
         longest_tag = len("[TASK: ]") + cap
         assert eng.router._tail >= longest_tag
-        assert eng.router._tail >= 8 * eng.max_window
+        assert eng.router._tail >= 8 * sync_every
         assert eng.router._tail >= 256
 
 
 def test_plausible_hint():
-    """plausible() == unclosed '[' in the retained tail: the adaptive
-    window policy shortens on it and the pipelined gate refuses to overlap
-    a ']'-bearing window while it holds."""
+    """plausible() == unclosed '[' in the retained tail: the pipelined gate
+    refuses to overlap a ']'-bearing window while it holds."""
     r = CortexRouter(tail=64)
     assert not r.plausible("a")             # unknown agent: nothing pending
     r.feed("a", "calm text, no brackets")

@@ -20,8 +20,7 @@ The contract this suite pins down:
   stream closes with status "cancelled";
 * SLOs — :meth:`ServingFrontend.metrics` reports per-request TTFT /
   queue-wait / TPOT, per-tenant token shares summing to 1, fairness
-  counters, and p50/p99 tick latency — the exact section
-  benchmarks/bench_serving.py records into BENCH_throughput.json.
+  counters, and p50/p99 tick latency.
 """
 import dataclasses
 import threading
